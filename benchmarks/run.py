@@ -13,14 +13,15 @@ Two kinds of benchmark live behind one registry and ONE `--smoke` flag:
     (`scripts/ci_check.sh`), so `--smoke` means the same reduced scale
     everywhere instead of per-file ad-hoc handling.
 
-`--tables all` (default) runs everything; `--fast` is kept as a deprecated
-alias for `--smoke`.
+`--tables all` (default) runs everything, `roofline` only where the dry-run
+artifacts it reads exist; a phase that fails fails the run. `--fast` is
+kept as a deprecated alias for `--smoke`.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import sys
+import os
 import time
 
 
@@ -65,11 +66,14 @@ def main(argv=None) -> None:
 
     from benchmarks.context import BenchContext
     from benchmarks.kernel_bench import kernel_rows
-    from benchmarks.roofline import roofline_rows
+    from benchmarks.roofline import DRYRUN_DIR, roofline_rows
     from benchmarks.tables import ALL_TABLES
 
     suites = _suite_registry()
-    want = list(ALL_TABLES) + ["roofline", "kernels"] + list(suites)
+    # roofline reads the dry-run's JSON artifacts; the default set runs it
+    # only where they exist, and an explicit request fails without them
+    default_roofline = ["roofline"] if os.path.isdir(DRYRUN_DIR) else []
+    want = list(ALL_TABLES) + default_roofline + ["kernels"] + list(suites)
     if args.tables != "all":
         want = args.tables.split(",")
     unknown = [t for t in want
@@ -95,10 +99,7 @@ def main(argv=None) -> None:
             if tname in ALL_TABLES:
                 rows.extend(ALL_TABLES[tname](ctx))
     if "roofline" in want:
-        try:
-            rows.extend(roofline_rows())
-        except Exception as e:  # dry-run artifacts missing
-            print(f"# roofline skipped: {e}", file=sys.stderr)
+        rows.extend(roofline_rows())
     if "kernels" in want:
         rows.extend(kernel_rows())
 

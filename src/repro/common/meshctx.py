@@ -1,18 +1,11 @@
-"""Version-portable mesh context: one place that knows how to ask JAX
-"which mesh is active?" and "make this mesh active".
+"""Mesh context: one place that asks JAX "which mesh is active?" and "make
+this mesh active", written against the installed JAX (0.9):
+``jax.sharding.get_abstract_mesh``, ``jax.set_mesh``,
+``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map`` and the dict
+returned by ``Compiled.cost_analysis()``.
 
-The mesh-context API has drifted across JAX releases:
-
-  * >= 0.5.x exposes ``jax.sharding.get_abstract_mesh`` / ``jax.set_mesh``
-    (earlier spelled ``jax.sharding.use_mesh``) and
-    ``jax.make_mesh(..., axis_types=...)`` with ``jax.sharding.AxisType``;
-  * 0.4.x keeps the same machinery under ``jax._src.mesh``
-    (``get_abstract_mesh``, ``thread_resources``) with activation via the
-    classic ``with mesh:`` resource-env context, ``jax.make_mesh`` without
-    ``axis_types``, and ``shard_map`` under ``jax.experimental.shard_map``;
-  * anything older still accepts a raw ``jax.sharding.Mesh`` context.
-
-Model/serving code must not care. The portability contract is:
+Model/serving code goes through these names only (the `mesh-api` analyzer
+rule enforces it):
 
   * ``current_mesh()`` returns the active mesh (concrete or abstract) or
     ``None``; never raises, never returns an *empty* mesh.
@@ -20,19 +13,14 @@ Model/serving code must not care. The portability contract is:
     (a) ``current_mesh()`` sees it from any thread-locally nested code,
     (b) bare-``PartitionSpec`` sharding constraints resolve inside ``jit``,
     (c) ``shard_map`` collectives can bind its axis names.
-  * ``make_mesh(shape, names)`` builds a mesh on every supported version.
+  * ``make_mesh(shape, names)`` builds a mesh with Auto (or Explicit) axes.
   * ``axis_sizes_dict(mesh)`` maps axis name -> size for concrete *and*
     abstract meshes.
-  * ``shard_map(...)`` resolves to the native implementation.
+  * ``shard_map`` is ``jax.shard_map``.
 
-Resolution order for ``current_mesh()``:
-
-  1. ``jax.sharding.get_abstract_mesh()`` (new-style sharding-in-types);
-  2. ``jax._src.mesh.get_abstract_mesh()`` (0.4.x internal spelling);
-  3. ``jax._src.mesh.thread_resources.env.physical_mesh`` (the classic
-     ``with mesh:`` resource env — what ``use_mesh`` sets on 0.4.x);
-  4. the thread-local registry maintained by ``use_mesh`` itself, which
-     works even on a hypothetical JAX with none of the above.
+``current_mesh()`` asks ``jax.sharding.get_abstract_mesh()`` first, then a
+thread-local registry that ``use_mesh`` maintains itself, so a mesh
+activated here is visible even where JAX reports none.
 """
 from __future__ import annotations
 
@@ -41,7 +29,6 @@ import threading
 from typing import Iterator, Optional, Sequence
 
 import jax
-import numpy as np
 from jax.sharding import Mesh
 
 __all__ = [
@@ -52,6 +39,8 @@ __all__ = [
     "shard_map",
     "cost_analysis_dict",
 ]
+
+shard_map = jax.shard_map
 
 # ---------------------------------------------------------------- resolution
 
@@ -67,37 +56,16 @@ def _registry_stack() -> list:
 
 def _nonempty(mesh) -> Optional[Mesh]:
     """Normalize: an empty / axis-less mesh counts as 'no mesh'."""
-    if mesh is None:
-        return None
-    if getattr(mesh, "empty", False):
-        return None
-    if not getattr(mesh, "axis_names", ()):
+    if mesh.empty or not mesh.axis_names:
         return None
     return mesh
 
 
 def current_mesh() -> Optional[Mesh]:
     """The active (concrete or abstract) mesh, or None outside any context."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = _nonempty(getter())
-        if mesh is not None:
-            return mesh
-    try:  # 0.4.x internal spelling of the same thing
-        from jax._src import mesh as _mesh_src
-
-        getter = getattr(_mesh_src, "get_abstract_mesh", None)
-        if getter is not None:
-            mesh = _nonempty(getter())
-            if mesh is not None:
-                return mesh
-        tr = getattr(_mesh_src, "thread_resources", None)
-        if tr is not None:
-            mesh = _nonempty(tr.env.physical_mesh)
-            if mesh is not None:
-                return mesh
-    except Exception:  # pragma: no cover - exotic JAX builds
-        pass
+    mesh = _nonempty(jax.sharding.get_abstract_mesh())
+    if mesh is not None:
+        return mesh
     stack = _registry_stack()
     return _nonempty(stack[-1]) if stack else None
 
@@ -107,26 +75,12 @@ def current_mesh() -> Optional[Mesh]:
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh) -> Iterator[Mesh]:
-    """Activate `mesh` for the calling thread (portable jax.set_mesh).
-
-    Prefers the newest native activation available so jit/GSPMD resolve
-    bare PartitionSpecs, then falls back to the classic ``with mesh:``
-    resource env, and always mirrors into the thread-local registry so
-    ``current_mesh()`` works regardless of JAX version.
-    """
+    """Activate `mesh` for the calling thread (``jax.set_mesh``), mirrored
+    into the thread-local registry that ``current_mesh()`` falls back to."""
     stack = _registry_stack()
     stack.append(mesh)
     try:
-        setter = getattr(jax, "set_mesh", None) or getattr(
-            jax.sharding, "use_mesh", None
-        )
-        if setter is not None:
-            with setter(mesh):
-                yield mesh
-        elif isinstance(mesh, Mesh):
-            with mesh:  # classic resource-env context (<= 0.4.x)
-                yield mesh
-        else:  # abstract mesh on a JAX without a native setter
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         stack.pop()
@@ -141,28 +95,11 @@ def make_mesh(
     *,
     explicit: bool = False,
 ) -> Mesh:
-    """``jax.make_mesh`` across versions (``axis_types`` appeared later).
-
-    `explicit=True` asks for sharding-in-types Explicit axes where the
-    running JAX supports them; otherwise Auto/classic semantics apply.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    factory = getattr(jax, "make_mesh", None)
-    if factory is not None and axis_type is not None:
-        kind = axis_type.Explicit if explicit else axis_type.Auto
-        try:
-            return factory(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(kind,) * len(tuple(axis_names)),
-            )
-        except TypeError:  # axis_types kwarg not in this signature
-            pass
-    if factory is not None:
-        return factory(tuple(axis_shapes), tuple(axis_names))
-    devices = np.array(jax.devices()[: int(np.prod(axis_shapes))]).reshape(
-        tuple(axis_shapes)
-    )
-    return Mesh(devices, tuple(axis_names))
+    """``jax.make_mesh`` with Auto axes, or sharding-in-types Explicit axes
+    when `explicit=True`."""
+    kind = jax.sharding.AxisType.Explicit if explicit else jax.sharding.AxisType.Auto
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names, axis_types=(kind,) * len(names))
 
 
 # ------------------------------------------------------------------- queries
@@ -170,35 +107,9 @@ def make_mesh(
 
 def axis_sizes_dict(mesh) -> dict:
     """{axis name: size} for concrete Mesh and AbstractMesh alike."""
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None and not callable(sizes):
-        return dict(zip(mesh.axis_names, sizes))
-    shape = getattr(mesh, "shape", None)
-    if shape is not None:
-        return dict(shape)
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """`Compiled.cost_analysis()` normalized across JAX versions.
-
-    0.4.x returns a one-dict-per-program list; newer releases return the
-    dict directly (and may return None when analysis is unavailable).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-# ------------------------------------------------------------------ shard_map
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # <= 0.4.x: experimental namespace, same semantics
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f=None, /, *, mesh, in_specs, out_specs, **kw):
-        if f is None:
-            return lambda g: _sm(g, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """`Compiled.cost_analysis()` as a dict ({} when the backend has none)."""
+    return compiled.cost_analysis() or {}

@@ -3,8 +3,9 @@
 The hot loop the paper constrains to single-digit milliseconds. Two
 implementations share one interface:
 
-  * `rank_dense` — jnp matmul + argsort (the CPU production path; also the
-    oracle for the Pallas kernel);
+  * `topk_dense` / `rank_dense` — fp32 jnp matmul + `lax.top_k`, exact on
+    every platform (the dense backend, the exact fallback, and the oracle
+    for the Pallas kernel);
   * `repro.kernels.topk_sim.ops.topk_sim` — the TPU-native fused
     similarity+top-K Pallas kernel for pod-co-located routers (DESIGN.md §4).
 
@@ -24,8 +25,14 @@ NEG_INF = -1e30
 
 
 def similarities(query_emb: jnp.ndarray, tool_emb: jnp.ndarray) -> jnp.ndarray:
-    """Cosine similarity assuming unit-normalized rows. [Q,D]x[T,D] -> [Q,T]."""
-    return query_emb @ tool_emb.T
+    """Cosine similarity assuming unit-normalized rows. [Q,D]x[T,D] -> [Q,T].
+
+    Pinned to fp32 (`Precision.HIGHEST`): this is the exact oracle and the
+    manager's exact fallback, and the TPU's default precision (one bf16
+    pass) misses the float32 scores by ~6e-4 at D=384 on a v5e, enough to
+    reorder near-ties; HIGHEST agrees to ~2e-7.
+    """
+    return jnp.matmul(query_emb, tool_emb.T, precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -9,8 +9,15 @@ routers co-located with TPU pods. TPU-native design (DESIGN.md §4):
     grid axis — one pass over the table, no global [Q, T] score matrix is
     ever materialized (the jnp reference writes Q*T floats to HBM; at
     T=100k tools that is the difference between streaming and spilling);
-  * the merge is a single descending sort over [K + BLOCK_T] candidates per
-    query row (K <= 64 << BLOCK_T, so sort cost is dominated by the tile).
+  * the merge is sort-free, because Mosaic has no sort: K rounds of "row
+    max, lowest column index among the maxima" over the carried top-K and
+    the tile's scores. Round r only admits candidates that rank strictly
+    after round r-1's winner in (score descending, index ascending) order,
+    so no candidate array is rewritten between rounds, and ties resolve to
+    the lowest index exactly like `lax.top_k`. The cost is K passes of
+    elementwise VPU work over the [BLOCK_Q, K + BLOCK_T] candidates.
+  * the contraction is pinned to fp32 (`Precision.HIGHEST`): the kernel's
+    contract is exact scores, and a bf16 MXU pass would reorder near-ties.
 
 Grid: (q_blocks, t_blocks), t innermost so the scratch carry is sequential.
 """
@@ -47,18 +54,42 @@ def _kernel(q_ref, t_ref, vals_out, idx_out, vals_s, idx_s, *, k: int, n_tools: 
     q = q_ref[...]  # [BQ, D]
     t = t_ref[...]  # [BT, D]
     scores = jax.lax.dot_general(
-        q, t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # [BQ, BT]
     base = ti * BLOCK_T
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + base
     # mask padding rows of the table (T padded up to a BLOCK_T multiple)
     scores = jnp.where(col < n_tools, scores, NEG)
 
-    cand_v = jnp.concatenate([vals_s[...], scores], axis=1)  # [BQ, K+BT]
-    cand_i = jnp.concatenate([idx_s[...], col], axis=1)
-    order = jnp.argsort(-cand_v, axis=1)[:, :k]
-    vals_s[...] = jnp.take_along_axis(cand_v, order, axis=1)
-    idx_s[...] = jnp.take_along_axis(cand_i, order, axis=1)
+    # carried entries come from earlier tiles, so every (score, index) pair
+    # that can win is distinct (the initial NEG slots lose to the first
+    # tile's real columns, of which there are at least K)
+    carry_v, carry_i = vals_s[...], idx_s[...]  # [BQ, K]
+    slot = jax.lax.broadcasted_iota(jnp.int32, carry_v.shape, 1)
+    big = jnp.int32(2**31 - 1)
+    new_v, new_i = carry_v, carry_i
+    prev_v = jnp.full((carry_v.shape[0], 1), jnp.inf, jnp.float32)
+    prev_i = jnp.full((carry_v.shape[0], 1), -1, jnp.int32)
+    for r in range(k):
+        # candidates ranked after the previous winner: lower score, or the
+        # same score at a higher index
+        ok_c = (carry_v < prev_v) | ((carry_v == prev_v) & (carry_i > prev_i))
+        ok_s = (scores < prev_v) | ((scores == prev_v) & (col > prev_i))
+        m = jnp.maximum(
+            jnp.max(jnp.where(ok_c, carry_v, -jnp.inf), axis=1, keepdims=True),
+            jnp.max(jnp.where(ok_s, scores, -jnp.inf), axis=1, keepdims=True),
+        )
+        sel = jnp.minimum(
+            jnp.min(jnp.where(ok_c & (carry_v == m), carry_i, big), axis=1, keepdims=True),
+            jnp.min(jnp.where(ok_s & (scores == m), col, big), axis=1, keepdims=True),
+        )
+        new_v = jnp.where(slot == r, m, new_v)
+        new_i = jnp.where(slot == r, sel, new_i)
+        prev_v, prev_i = m, sel
+    vals_s[...] = new_v
+    idx_s[...] = new_i
 
     @pl.when(ti == nt - 1)
     def _emit():

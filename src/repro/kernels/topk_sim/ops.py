@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the fused similarity+top-K op.
 
 `use_pallas=None` auto-selects: the Pallas kernel on TPU backends, the jnp
-reference elsewhere (this CPU container validates the kernel body with
-interpret=True in tests).
+reference elsewhere (on the CPU, tests run the kernel body with
+interpret=True).
 """
 from __future__ import annotations
 

@@ -12,6 +12,7 @@ def topk_sim_ref(
     table: jnp.ndarray,  # [T, D] unit rows
     k: int,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (scores [Q, k], indices [Q, k]) by descending similarity."""
-    sims = queries @ table.T
+    """Returns (scores [Q, k], indices [Q, k]) by descending similarity,
+    with the contraction in fp32 like the kernel's (`Precision.HIGHEST`)."""
+    sims = jnp.matmul(queries, table.T, precision=jax.lax.Precision.HIGHEST)
     return jax.lax.top_k(sims, k)

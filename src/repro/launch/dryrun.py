@@ -1,14 +1,17 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, and record memory/cost/collective analysis.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape all --mesh both
 
-The XLA_FLAGS line above MUST stay the first statement — jax locks the device
-count at first init, and the dry-run (and only the dry-run) needs 512
-placeholder host devices for `jax.make_mesh((2,16,16), ...)`.
+The XLA_FLAGS and JAX_PLATFORMS lines above MUST stay the first statements —
+jax locks the device count at first init, and the dry-run (and only the
+dry-run) needs 512 placeholder host devices for `jax.make_mesh((2,16,16),
+...)`. It is a CPU-only tool: pinning the platform keeps it off an attached
+accelerator, which belongs to one process at a time.
 
 Each run writes experiments/dryrun/<arch>__<shape>__<mesh>.json with:
   * per-device memory_analysis (argument/output/temp bytes) — proves it fits,

@@ -5,8 +5,10 @@
 
 Wires together the full paper pipeline (Fig. 2): a synthetic MetaTool-like
 tool database, the OATS offline refinement job (Stage 1 + validation gate +
-atomic table swap), the CPU serving path (embed -> top-K -> attach tools),
-and a backend model pool doing real prefill+decode on a reduced config.
+atomic table swap), the serving path (embed -> top-K -> attach tools), and a
+backend model pool doing real prefill+decode: at full width, or on the
+`reduced` config under --smoke. A chosen index backend that never becomes
+fresh, or a non-finite logit from the pool, is an error, not a fallback.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.pipeline import OATSPipeline, PipelineConfig, STAGE_PRESETS
 from repro.data.benchmarks import make_metatool_like, scale_tool_corpus
@@ -129,11 +132,12 @@ def build_router(
     # emits the `cache_invalidated` event the runbook watches)
     if cache is not None and bus is not None:
         detach(cache.watch(bus))
-    # demo timing should reflect the index path, not the mid-build fallback
+    # the chosen backend must be what serves: an index that never becomes
+    # fresh would leave the exact dense fallback serving under its name
     if not router.index.wait_ready(timeout_s=300.0):
-        print(
-            f"WARNING: {backend} index never became fresh "
-            f"(stats: {router.index.stats}); serving the exact dense fallback"
+        router.close()
+        raise RuntimeError(
+            f"{backend} index never became fresh (stats: {router.index.stats})"
         )
     return router, pipe
 
@@ -191,6 +195,7 @@ def main(argv=None):
                          "n_tables (8) slots, LRU-evicted beyond this")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # telemetry plane: metrics go to the process registry (the router
     # records into it by default), lifecycle events to one shared bus,
@@ -304,10 +309,11 @@ def _serve_body(args, bench, router, pipe, bus, tracer, quality, monitor):
     if args.smoke:
         cfg = reduced(cfg)
     params = M.init(cfg, jax.random.PRNGKey(args.seed))
+    prefill = jax.jit(lambda p, b: M.prefill(cfg, p, b, max_cache_len=64))
     decode = jax.jit(lambda p, c, b: M.decode_step(cfg, p, c, b))
 
     test = bench.test_idx[: args.requests]
-    hits, lat = 0, []
+    hits, lat, n_decoded = 0, [], 0
     t_start = time.time()
     rng = np.random.default_rng(args.seed)
     # 1) router: select tools on CPU (the paper's single-digit-ms path),
@@ -328,13 +334,18 @@ def _serve_body(args, bench, router, pipe, bus, tracer, quality, monitor):
         batch = {"tokens": prompt}
         if cfg.cross_attn_every:
             batch["image_embeds"] = jnp.zeros((1, cfg.n_image_tokens, cfg.d_model))
-        logits, cache = M.prefill(cfg, params, batch, max_cache_len=64)
-        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        if cfg.n_codebooks:
-            tok = tok  # [1,1,K] already
+        logits, cache = prefill(params, batch)
+        finite = jnp.isfinite(logits).all()
+        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)  # [1,1(,K)]
         for step in range(args.max_new_tokens - 1):
             logits, cache = decode(params, cache, {"token": tok, "pos": jnp.asarray(32 + step, jnp.int32)})
+            finite &= jnp.isfinite(logits).all()
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        if not bool(finite):
+            raise FloatingPointError(
+                f"{cfg.name}: non-finite logits while decoding request {qi}"
+            )
+        n_decoded += args.max_new_tokens
         # 3) feedback: log the outcome for the next refinement cycle
         for t in res.tools:
             router.record_outcome(bench.query_tokens[qi], t, int(t in bench.relevant[qi]))
@@ -345,6 +356,8 @@ def _serve_body(args, bench, router, pipe, bus, tracer, quality, monitor):
         f"router R@{router.k}: {hits / len(test):.3f} | "
         f"selection p50={stats.p50_ms:.2f}ms p99={stats.p99_ms:.2f}ms"
     )
+    print(f"pool: {cfg.name} decoded {n_decoded} tokens for {len(test)} "
+          f"requests, every logit finite")
     print(f"outcome log: {len(router.outcome_log)} events (feeds the next cron refinement)")
     print(f"index stats: {router.index.stats}")
     if router.cache is not None:

@@ -45,28 +45,20 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.analysis.retrace import supports_cache_size
+from repro.common.meshctx import cost_analysis_dict
 
 __all__ = ["JitProfiler", "SamplingProfiler", "stamp_router_costs"]
 
 
-def _cost_analysis_dict(compiled) -> dict:
-    """Normalize XLA's cost_analysis across jax versions (list-of-dict or
-    dict) into {"flops": float, "bytes_accessed": float}."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend may not implement it
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return {}
+def _cost_summary(compiled) -> dict:
+    """XLA's cost analysis as {"flops": float, "bytes_accessed": float}
+    (keys absent where the backend reports none)."""
+    ca = cost_analysis_dict(compiled)
     out = {}
     if "flops" in ca:
         out["flops"] = float(ca["flops"])
-    for k in ("bytes accessed", "bytes_accessed"):
-        if k in ca:
-            out["bytes_accessed"] = float(ca[k])
-            break
+    if "bytes accessed" in ca:
+        out["bytes_accessed"] = float(ca["bytes accessed"])
     return out
 
 
@@ -142,7 +134,7 @@ class JitProfiler:
         manufactures the retrace signal it exists to watch for.
         """
         fn = self._fns[name]
-        cost = _cost_analysis_dict(fn.lower(*args, **kwargs).compile())
+        cost = _cost_summary(fn.lower(*args, **kwargs).compile())
         cost["arg_shapes"] = [
             list(np.shape(a)) for a in args if hasattr(a, "shape")
         ]

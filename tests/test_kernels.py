@@ -36,7 +36,8 @@ def test_topk_sim_shapes(q, t, d, k):
 def test_topk_sim_tie_handling():
     """Rows with BITWISE-tied scores spanning the BLOCK_T tile boundary:
     kernel and ref must both resolve ties to the LOWEST index (the kernel's
-    stable merge sort keeps earlier-tile candidates ahead of later ones,
+    sort-free merge takes the lowest index among tied maxima, and carried
+    earlier-tile candidates have lower indices than the current tile's,
     matching lax.top_k's tie order) — pinned before the Pallas path serves
     traffic. One-hot table rows make every duplicate's dot product a single
     float term, so ties are exact regardless of GEMM summation order

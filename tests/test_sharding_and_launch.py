@@ -139,3 +139,26 @@ def test_single_device_lowering_smoke():
     compiled = lowered.compile()
     from repro.common.meshctx import cost_analysis_dict
     assert cost_analysis_dict(compiled)["flops"] > 0
+
+
+def test_compile_cache_env_dir_wins_else_one_checkout_dir(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no cache path (JAX
+    reads the variable itself); without it the cache goes to the one fixed
+    directory inside the checkout, which git ignores."""
+    from pathlib import Path
+
+    from repro.common import compile_cache
+
+    repo = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        assert got == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().splitlines()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

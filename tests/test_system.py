@@ -50,3 +50,21 @@ def test_train_launcher_loss_drops():
         "--batch-size", "2", "--seq-len", "64",
     ])
     assert history[-1]["loss"] <= history[0]["loss"] + 0.05
+
+
+def test_build_router_raises_when_chosen_index_never_serves(small_bench, monkeypatch):
+    """A backend whose build fails must stop the launcher, not leave the
+    exact dense fallback serving under the backend's name."""
+    from repro.index import manager
+    from repro.launch.serve import build_router
+
+    real = manager._build_backend
+
+    def failing_for_live_versions(kind, table, version, **opts):
+        if version >= 0:  # the constructor's validation build (-1) passes
+            raise RuntimeError("injected build failure")
+        return real(kind, table, version, **opts)
+
+    monkeypatch.setattr(manager, "_build_backend", failing_for_live_versions)
+    with pytest.raises(RuntimeError, match="never became fresh"):
+        build_router(small_bench, "oats-s1", backend="pallas")
