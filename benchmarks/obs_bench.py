@@ -215,7 +215,7 @@ def run_overhead(bench, enc, smoke: bool, seed: int) -> dict:
         name: stats_from_histogram(
             registry.histogram("route_phase_ms", phase=name)
         ).as_dict()
-        for name in ("embed", "cache", "adapter", "score", "assemble")
+        for name in ("embed", "cache", "pad", "score", "assemble")
     }
     total = stats_from_histogram(registry.histogram("route_batch_ms")).as_dict()
     row = {

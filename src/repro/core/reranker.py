@@ -135,11 +135,12 @@ def rerank_topk_scored(
     serving code can report the ranking signal actually used (not the
     pre-rerank similarities, which may order differently).
     """
-    scores = mlp_forward(params, features)  # [Q, C]
-    if valid is not None:
-        scores = jnp.where(valid, scores, -1e30)
-    top_scores, order = jax.lax.top_k(scores, k)
-    return jnp.take_along_axis(cand_idx, order, axis=1), top_scores
+    with jax.named_scope("rerank"):
+        scores = mlp_forward(params, features)  # [Q, C]
+        if valid is not None:
+            scores = jnp.where(valid, scores, -1e30)
+        top_scores, order = jax.lax.top_k(scores, k)
+        return jnp.take_along_axis(cand_idx, order, axis=1), top_scores
 
 
 def rerank_topk(

@@ -42,11 +42,15 @@ def topk_dense(
     k: int,
     candidate_mask: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Top-k (scores, indices) per query. candidate_mask: [Q,T] {0,1} or None."""
-    sims = similarities(query_emb, tool_emb)
-    if candidate_mask is not None:
-        sims = jnp.where(candidate_mask > 0, sims, NEG_INF)
-    return jax.lax.top_k(sims, k)
+    """Top-k (scores, indices) per query. candidate_mask: [Q,T] {0,1} or None.
+
+    The device ops carry their step in the op metadata (`score/`, `topk/`)."""
+    with jax.named_scope("score"):
+        sims = similarities(query_emb, tool_emb)
+        if candidate_mask is not None:
+            sims = jnp.where(candidate_mask > 0, sims, NEG_INF)
+    with jax.named_scope("topk"):
+        return jax.lax.top_k(sims, k)
 
 
 def rank_dense(

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.retrieval import topk_dense
+from repro.index.base import round_trip
 
 __all__ = ["DenseBackend"]
 
@@ -35,6 +36,7 @@ class DenseBackend:
         self.table_version = int(table_version)
         self.n_tools = table.shape[0]
         self._table_j = jnp.asarray(table)  # device-resident, built once
+        self.upload_bytes = self._table_j.nbytes
 
     def topk(
         self,
@@ -42,6 +44,7 @@ class DenseBackend:
         k: int,
         candidate_mask: Optional[np.ndarray] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
-        mask_j = None if candidate_mask is None else jnp.asarray(candidate_mask)
-        scores, idx = topk_dense(jnp.asarray(queries), self._table_j, k, mask_j)
-        return np.asarray(scores), np.asarray(idx)
+        return round_trip(
+            lambda q, mask: topk_dense(q, self._table_j, k, mask),
+            queries, candidate_mask,
+        )

@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.retrieval import NEG_INF
 from repro.models.quant import quantize_tree
+from repro.obs.trace import current_spans
 
 __all__ = ["IVFConfig", "IVFBackend"]
 
@@ -171,6 +172,11 @@ class IVFBackend:
             "clusters would silently vanish); ToolIndexManager routes masked "
             "batches to the exact fallback"
         )
+        # host NumPy end to end: one span, no device round trip to split
+        with current_spans().span("index.ivf"):
+            return self._topk(queries, k)
+
+    def _topk(self, queries: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
         q = np.asarray(queries, np.float32)
         n_q = q.shape[0]
         if n_q == 0:  # contract: any Q, including an empty batch

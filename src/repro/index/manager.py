@@ -31,6 +31,12 @@ build itself and doubles the uploads; only genuinely expensive builds
 A failed build (bad table, backend bug) is counted in
 `stats["build_failures"]` and leaves the fallback serving — an index is an
 optimization, never a correctness dependency.
+
+Each `topk` call's snapshot, backend lookup and version check is the
+`index.snapshot` span of the calling batch (`repro.obs.trace`); the
+backend's own round trip times the rest. The table each build uploads
+(`upload_bytes` of a device backend) counts into
+`index_transfer_bytes_total{dir=h2d}`, so a swap's upload shows there.
 """
 from __future__ import annotations
 
@@ -43,6 +49,7 @@ import numpy as np
 from repro.index.dense import DenseBackend
 from repro.obs import clock
 from repro.obs.metrics import MetricsRegistry, get_registry
+from repro.obs.trace import current_spans
 from repro.router.tooldb import ToolsDatabase
 
 __all__ = ["ToolIndexManager"]
@@ -60,6 +67,11 @@ class _IndexInstruments:
         self.rebuilds = registry.counter("index_rebuilds_total")
         self.build_failures = registry.counter("index_build_failures_total")
         self.build_ms = registry.histogram("index_build_ms")
+        self.h2d_bytes = registry.counter("index_transfer_bytes_total", dir="h2d")
+
+    def uploaded(self, backend) -> None:
+        """Count the table a device backend uploaded when it was built."""
+        self.h2d_bytes.inc(getattr(backend, "upload_bytes", 0))
 
 
 def _build_backend(kind: str, table: np.ndarray, table_version: int, **opts):
@@ -127,9 +139,11 @@ class ToolIndexManager:
         # validation build surfaces TypeError/ValueError at construction
         # instead of a silent build-failure loop behind the fallback
         _, probe_table = db.snapshot()
-        _build_backend(
+        probe = _build_backend(
             backend, np.asarray(probe_table[:64]), -1, **self.backend_opts
         )
+        if self._obs is not None:
+            self._obs.uploaded(probe)
         self._watching = watch_swaps
         if watch_swaps:
             db.add_swap_listener(self._on_swap)
@@ -250,6 +264,7 @@ class ToolIndexManager:
         if obs is not None:
             obs.rebuilds.inc()
             obs.build_ms.record(build_ms)
+            obs.uploaded(backend)
         if bus is not None:
             bus.publish("rebuild_finish", plane="index", version=version,
                         backend=self.backend_kind, build_ms=build_ms)
@@ -266,10 +281,12 @@ class ToolIndexManager:
         The returned version is the snapshot the scores were computed from —
         the backend's when it serves, the fallback snapshot's otherwise.
         """
-        version, table = self.db.snapshot()
-        with self._lock:
-            backend = self._backend
-        if backend is None or backend.table_version != version:
+        with current_spans().span("index.snapshot"):
+            version, table = self.db.snapshot()
+            with self._lock:
+                backend = self._backend
+            stale = backend is None or backend.table_version != version
+        if stale:
             # cheap builds (a device upload) run inline — the PR 1 serving
             # path paid exactly this upload on version change; expensive
             # builds (IVF) go async and this batch serves the exact fallback
@@ -315,4 +332,6 @@ class ToolIndexManager:
         if fallback is None or fallback.table_version != version:
             fallback = DenseBackend(table, version)
             self._fallback = fallback
+            if self._obs is not None:
+                self._obs.uploaded(fallback)
         return fallback.topk(queries, k, candidate_mask)

@@ -25,6 +25,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.index.base import round_trip
 from repro.kernels.topk_sim.ops import topk_sim
 
 __all__ = ["PallasBackend"]
@@ -48,6 +49,7 @@ class PallasBackend:
         self.use_pallas = use_pallas
         self.interpret = interpret
         self._table_j = jnp.asarray(table)
+        self.upload_bytes = self._table_j.nbytes
 
     def topk(
         self,
@@ -60,11 +62,10 @@ class PallasBackend:
             "support); ToolIndexManager routes masked batches to the exact "
             "fallback"
         )
-        scores, idx = topk_sim(
-            jnp.asarray(queries),
-            self._table_j,
-            k,
-            use_pallas=self.use_pallas,
-            interpret=self.interpret,
+        return round_trip(
+            lambda q, _mask: topk_sim(
+                q, self._table_j, k,
+                use_pallas=self.use_pallas, interpret=self.interpret,
+            ),
+            queries,
         )
-        return np.asarray(scores), np.asarray(idx)

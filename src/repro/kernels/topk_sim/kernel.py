@@ -117,7 +117,7 @@ def topk_sim_pallas(
     qq, tt, dd = q + qp, t + tp, d + dp
 
     grid = (qq // BLOCK_Q, tt // BLOCK_T)
-    vals, idx = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(_kernel, k=k, n_tools=t),
         grid=grid,
         in_specs=[
@@ -137,5 +137,8 @@ def topk_sim_pallas(
             pltpu.VMEM((BLOCK_Q, k), jnp.int32),
         ],
         interpret=interpret,
-    )(queries, table)
+    )
+    # one op scores and selects: its metadata names both steps
+    with jax.named_scope("score"), jax.named_scope("topk"):
+        vals, idx = kernel(queries, table)
     return vals[:q], idx[:q]

@@ -14,5 +14,7 @@ def topk_sim_ref(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (scores [Q, k], indices [Q, k]) by descending similarity,
     with the contraction in fp32 like the kernel's (`Precision.HIGHEST`)."""
-    sims = jnp.matmul(queries, table.T, precision=jax.lax.Precision.HIGHEST)
-    return jax.lax.top_k(sims, k)
+    with jax.named_scope("score"):
+        sims = jnp.matmul(queries, table.T, precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope("topk"):
+        return jax.lax.top_k(sims, k)

@@ -9,9 +9,11 @@ qps). Four surfaces:
 * `repro.obs.metrics` — process-wide `MetricsRegistry` of counters, gauges,
   and preallocated log-spaced-bucket histograms (O(1) record, bounded
   memory); Prometheus text exposition + JSON snapshot.
-* `repro.obs.trace` — seeded ~1-in-N sampled `RouteTracer`: per-batch phase
-  spans stamped with versions, JSONL export, rendered by ``repro-obs``
-  (`repro.obs.report`).
+* `repro.obs.trace` — `SpanRecorder`, the route path's one span primitive
+  (per-batch ``with spans.span(name):`` blocks on `clock.perf()`, mirrored
+  as profiler `TraceAnnotation`s while a profiler trace is active), and the
+  seeded ~1-in-N sampled `RouteTracer`: per-batch spans stamped with
+  versions, JSONL export, rendered by ``repro-obs`` (`repro.obs.report`).
 * `repro.obs.events` — bounded `EventBus` the control/learn/index planes
   publish lifecycle transitions into (replacing scattered prints and
   write-only attributes).
@@ -74,14 +76,31 @@ route_requests_total (counter)
     Queries routed, summed over batches.
 route_batches_total (counter)
     `route_batch` calls served.
-route_phase_ms{phase=embed|cache|adapter|score|rerank|assemble} (histogram)
-    Per-batch wall duration of each serving phase, monotonic clock.
+route_phase_ms{phase=embed|cache|pad|adapter|score|rerank|assemble} (histogram)
+    Per-batch wall duration of each serving phase that ran, monotonic
+    clock: `pad` is the copy of the miss rows into their power-of-two
+    bucket; `adapter` and `rerank` only when that learned stage ran.
 route_batch_ms (histogram)
-    End-to-end per-batch duration (sum of phases + overhead).
+    End-to-end per-batch duration, entry to the end of `assemble` (the
+    phases + overhead; the gateway's own telemetry after it is not in it).
+route_obs_ms (histogram)
+    The gateway's own telemetry per batch: trace record, histogram and
+    counter records, score-gap pass (recorded after it, not timing itself).
 route_batch_size (histogram)
     Raw batch sizes (pre pow2 padding).
-route_table_version / route_stage_version (gauge)
-    Versions stamped on the most recent batch.
+index_step_ms{step=snapshot|upload|dispatch|wait|fetch|ivf} (histogram)
+    Per-call duration of each index-layer step inside the `score` phase:
+    the manager's snapshot + backend lookup, then the device backends' host
+    round trip (`repro.index.base.round_trip`) — query upload, jitted
+    dispatch, `wait` until the scores are on the host (the device's queue
+    and run land here: dispatch is asynchronous; then the scores' copy),
+    `fetch` of the indices (one copy of the same size); or the IVF
+    backend's one host span. Recorded by the gateway with its phases.
+index_transfer_bytes_total{dir=h2d|d2h} (counter)
+    Bytes moved between host and device by the index layer: per call the
+    padded query block (+ mask) up and scores + indices down (gateway
+    registry), per build the table a device backend uploads (manager
+    registry; the same one by default).
 route_outcomes_dropped_total (counter)
     Outcome-ring overwrites in `record_outcome` (undrained router).
 route_cache_hits_total / route_cache_misses_total (counter)
@@ -125,6 +144,20 @@ jit_cache_size{fn=} (gauge)
     Absolute compile-cache size per hot-path jit (warmup included).
 flightrec_dumps_total / flightrec_suppressed_total (counter)
     Black-box dumps written vs suppressed by the debounce window.
+
+Profiler span catalog (`SpanRecorder`, while a profiler trace is active)
+======================================================================
+
+route.embed, route.cache, route.pad, route.adapter, route.score,
+route.rerank, route.assemble, route.telemetry
+    The gateway's phases (histogram label: the name after ``route.``).
+index.snapshot, index.upload, index.dispatch, index.wait, index.fetch,
+index.ivf
+    The index layer's steps, inside ``route.score``.
+
+Device ops carry their step in the op metadata: ``score/`` and ``topk/``
+(`core.retrieval.topk_dense`, the Pallas top-K kernel), ``rerank/``
+(`core.reranker.rerank_topk_scored`).
 
 Event catalog (kind / plane / required detail stamps)
 =====================================================

@@ -82,12 +82,12 @@ def render_trace_report(records: List[dict]) -> str:
         for name, ms in r["spans"].items():
             by_phase.setdefault(name, []).append(float(ms))
     by_phase["total"] = [float(r["total_ms"]) for r in records]
-    lines.append(f"{'phase':10s} {'n':>6s} {'p50_ms':>9s} {'p99_ms':>9s} "
+    lines.append(f"{'phase':14s} {'n':>6s} {'p50_ms':>9s} {'p99_ms':>9s} "
                  f"{'mean_ms':>9s}")
     for name, samples in sorted(by_phase.items()):
         s = percentile_stats(samples)
         lines.append(
-            f"{name:10s} {s.n:6d} {s.p50_ms:9.3f} {s.p99_ms:9.3f} "
+            f"{name:14s} {s.n:6d} {s.p50_ms:9.3f} {s.p99_ms:9.3f} "
             f"{s.mean_ms:9.3f}"
         )
     return "\n".join(lines) + "\n"

@@ -72,6 +72,7 @@ from repro.core.retrieval import NEG_INF
 from repro.index import ToolIndexManager
 from repro.obs import clock
 from repro.obs.metrics import MetricsRegistry, get_registry
+from repro.obs.trace import SpanRecorder
 from repro.router.stages import StageSet
 from repro.router.tooldb import ConflictError, ToolsDatabase
 
@@ -83,7 +84,10 @@ __all__ = [
     "hot_path_jits",
 ]
 
-PHASES = ("embed", "cache", "adapter", "score", "rerank", "assemble")
+PHASES = ("embed", "cache", "pad", "adapter", "score", "rerank", "assemble")
+# index-layer steps inside the score phase (`repro.index.base.round_trip`,
+# the manager's snapshot, the IVF backend's one host span)
+INDEX_STEPS = ("snapshot", "upload", "dispatch", "wait", "fetch", "ivf")
 
 
 def hot_path_jits() -> "OrderedDict[str, Callable]":
@@ -124,8 +128,15 @@ class _GatewayInstruments:
             name: registry.histogram("route_phase_ms", phase=name)
             for name in PHASES
         }
-        self.table_version = registry.gauge("route_table_version")
-        self.stage_version = registry.gauge("route_stage_version")
+        self.index_step = {
+            name: registry.histogram("index_step_ms", step=name)
+            for name in INDEX_STEPS
+        }
+        self.transfer_bytes = {
+            d: registry.counter("index_transfer_bytes_total", dir=d)
+            for d in ("h2d", "d2h")
+        }
+        self.obs_ms = registry.histogram("route_obs_ms")
         self.outcomes_dropped = registry.counter("route_outcomes_dropped_total")
         # top-1/top-2 score gap per query (routing confidence; a collapsing
         # gap means the router is guessing) — recorded via record_many, one
@@ -403,8 +414,14 @@ class SemanticRouter:
         obs = self._obs
         tracing = self._tracer is not None and self._tracer.sample()
         timed = tracing or obs is not None
-        q = self._embed_batch(queries)  # [Q, D]
-        t_embed = clock.perf() if timed else 0.0
+        # spans exist only for work that actually ran: the cache span only
+        # when a cache is attached, pad/score only when misses reached the
+        # index, adapter/rerank only when that learned stage ran — recording
+        # ~0 ms identity "adapters" or slice-only "reranks" (or all-hit
+        # "scores") would poison the p50
+        spans = SpanRecorder(enabled=timed, t0=t0)
+        with spans.span("route.embed", start=t0):
+            q = self._embed_batch(queries)  # [Q, D]
         # cache probe (repro.cache): keys are embedding-space, so it runs
         # after embed and before everything a hit row gets to skip (index
         # backend + Stage-2 re-ranker). Masked batches bypass the cache
@@ -417,30 +434,30 @@ class SemanticRouter:
         cache = self._cache
         use_cache = cache is not None and candidate_masks is None
         if use_cache:
-            tv_live = self.db.table_version
-            cached = cache.lookup_batch(
-                q, table_version=tv_live, stage_version=stage_version
-            )
-            # tripwire, independent of the cache's own stamp check: any
-            # entry whose versions differ from the live pair is demoted to
-            # a miss (never served) and counted —
-            # route_cache_stale_served_total must stay 0 (cache_staleness
-            # SLO; benchmarks/cache_bench.py gates it in CI)
-            stale = 0
-            for j, e in enumerate(cached):
-                if e is not None and (
-                    e.table_version != tv_live
-                    or e.stage_version != stage_version
-                ):
-                    cached[j] = None
-                    stale += 1
-            if stale and obs is not None:
-                obs.cache_stale.inc(stale)
-            miss_idx = [j for j, e in enumerate(cached) if e is None]
+            with spans.span("route.cache"):
+                tv_live = self.db.table_version
+                cached = cache.lookup_batch(
+                    q, table_version=tv_live, stage_version=stage_version
+                )
+                # tripwire, independent of the cache's own stamp check: any
+                # entry whose versions differ from the live pair is demoted
+                # to a miss (never served) and counted —
+                # route_cache_stale_served_total must stay 0 (cache_staleness
+                # SLO; benchmarks/cache_bench.py gates it in CI)
+                stale = 0
+                for j, e in enumerate(cached):
+                    if e is not None and (
+                        e.table_version != tv_live
+                        or e.stage_version != stage_version
+                    ):
+                        cached[j] = None
+                        stale += 1
+                if stale and obs is not None:
+                    obs.cache_stale.inc(stale)
+                miss_idx = [j for j, e in enumerate(cached) if e is None]
         else:
             cached = []
             miss_idx = list(range(n_q))
-        t_cache = clock.perf() if timed else 0.0
         n_miss = len(miss_idx)
         # swap_table asserts the table shape is invariant, so the tool count
         # is stable across versions and safe to read without a snapshot
@@ -451,29 +468,30 @@ class SemanticRouter:
         if n_miss:
             # the scoring path sees only the miss rows: a mostly-hit batch
             # pays the index backend and re-ranker for its misses alone
-            if n_miss == n_q:
-                q_miss, queries_miss, masks_miss = q, queries, candidate_masks
-            else:
-                q_miss = q[miss_idx]
-                queries_miss = [queries[j] for j in miss_idx]
-                masks_miss = None  # masked batches never reach this branch
-            # pad the miss block up to a power-of-two bucket so the jitted
-            # scoring programs compile once per bucket, not once per
-            # distinct Q (the scheduler's admission batches vary with free
-            # slots; a retrace is a multi-ms stall against the 10 ms
-            # budget). Pad rows are zero queries whose results are sliced
-            # away below.
-            n_pad = pad_amount(n_miss)
-            if n_pad:
-                q_in = np.concatenate(
-                    [q_miss, np.zeros((n_pad, q.shape[1]), np.float32)]
-                )
-                queries_in = list(queries_miss) + [np.zeros(0, np.int64)] * n_pad
-                masks_in = None if masks_miss is None else np.concatenate(
-                    [masks_miss, np.ones((n_pad, n_t), masks_miss.dtype)]
-                )
-            else:
-                q_in, queries_in, masks_in = q_miss, queries_miss, masks_miss
+            with spans.span("route.pad"):
+                if n_miss == n_q:
+                    q_miss, queries_miss, masks_miss = q, queries, candidate_masks
+                else:
+                    q_miss = q[miss_idx]
+                    queries_miss = [queries[j] for j in miss_idx]
+                    masks_miss = None  # masked batches never reach this branch
+                # pad the miss block up to a power-of-two bucket so the
+                # jitted scoring programs compile once per bucket, not once
+                # per distinct Q (the scheduler's admission batches vary with
+                # free slots; a retrace is a multi-ms stall against the 10 ms
+                # budget). Pad rows are zero queries whose results are sliced
+                # away below.
+                n_pad = pad_amount(n_miss)
+                if n_pad:
+                    q_in = np.concatenate(
+                        [q_miss, np.zeros((n_pad, q.shape[1]), np.float32)]
+                    )
+                    queries_in = list(queries_miss) + [np.zeros(0, np.int64)] * n_pad
+                    masks_in = None if masks_miss is None else np.concatenate(
+                        [masks_miss, np.ones((n_pad, n_t), masks_miss.dtype)]
+                    )
+                else:
+                    q_in, queries_in, masks_in = q_miss, queries_miss, masks_miss
             # adapter head (query-side only) runs BEFORE the index backend —
             # the tool table is untouched, so any built IVF/Pallas index
             # stays valid across adapter promotions — and on the PADDED
@@ -482,104 +500,91 @@ class SemanticRouter:
             # multi-ms stall against the budget). pool_selector below keeps
             # seeing the raw encoder embedding `q`: pool affinity must not
             # flip on stage promotions/demotions.
-            q_in = stages.adapt_queries(q_in)
-            t_adapter = clock.perf() if timed else 0.0
+            if stages.has_adapter:
+                with spans.span("route.adapter"):
+                    q_in = stages.adapt_queries(q_in)
             # the index layer scores the batch against an atomic
             # (version, table) snapshot — the reported table_version and
             # the scores come from the SAME table even if swap_table lands
             # mid-batch, whichever backend (or the exact mid-rebuild
-            # fallback) served it
-            cand_scores_np, cand_idx_np, table_version = self.index.topk(
-                q_in, c, masks_in
-            )
-            t_score = clock.perf() if timed else 0.0
-            if rerank:
-                feats = stages.featurizer.features(q_in, queries_in, cand_idx_np, cand_scores_np)
-                top_idx, top_scores = reranker_lib.rerank_topk_scored(
-                    stages.mlp_params,
-                    jnp.asarray(feats),
-                    jnp.asarray(cand_idx_np),
-                    k_eff,
-                    valid=jnp.asarray(cand_scores_np > NEG_INF / 2),
+            # fallback) served it. Bound to this thread for the call, the
+            # recorder takes the index layer's step spans and bytes.
+            with spans.span("route.score"), spans.bound():
+                cand_scores_np, cand_idx_np, table_version = self.index.topk(
+                    q_in, c, masks_in
                 )
+            if rerank:
+                with spans.span("route.rerank"):
+                    feats = stages.featurizer.features(
+                        q_in, queries_in, cand_idx_np, cand_scores_np
+                    )
+                    top_idx, top_scores = reranker_lib.rerank_topk_scored(
+                        stages.mlp_params,
+                        jnp.asarray(feats),
+                        jnp.asarray(cand_idx_np),
+                        k_eff,
+                        valid=jnp.asarray(cand_scores_np > NEG_INF / 2),
+                    )
+                    top_idx = np.asarray(top_idx)[:n_miss]
+                    top_scores = np.asarray(top_scores)[:n_miss]
             else:
-                top_idx, top_scores = cand_idx_np[:, :k_eff], cand_scores_np[:, :k_eff]
-            top_idx = np.asarray(top_idx)[:n_miss]
-            top_scores = np.asarray(top_scores)[:n_miss]
+                top_idx = cand_idx_np[:n_miss, :k_eff]
+                top_scores = cand_scores_np[:n_miss, :k_eff]
         else:
             # every row hit: the adapter, index backend, and re-ranker are
             # all skipped, and the batch reports the live pair the hits
             # were verified against
-            t_adapter = t_score = t_cache
             table_version = tv_live
             top_idx = np.zeros((0, k_eff), np.int64)
             top_scores = np.zeros((0, k_eff), np.float32)
-        t_rank = clock.perf()
-        latency_ms = (t_rank - t0) * 1e3 / n_q
-        # a mask can leave fewer than k candidates; those slots carry the
-        # NEG_INF sentinel and must not surface as selected tools
-        miss_tools: List[List[int]] = []
-        miss_scores: List[List[float]] = []
-        for m in range(n_miss):
-            valid_m = top_scores[m] > NEG_INF / 2
-            miss_tools.append([int(t) for t in top_idx[m][valid_m]])
-            miss_scores.append([float(s) for s in top_scores[m][valid_m]])
-        if use_cache and n_miss:
-            # fresh decisions enter the cache stamped with the versions
-            # that actually produced them: the topk snapshot's
-            # table_version plus the batch's stage snapshot — NOT tv_live,
-            # which a mid-batch swap may already have left behind
-            cache.insert_batch(
-                q_miss, miss_tools, miss_scores,
-                table_version=table_version, stage_version=stage_version,
-            )
-        out = []
-        m = 0
-        for j in range(n_q):
-            e = cached[j] if use_cache else None
-            if e is not None:
-                tools, scores = list(e.tools), list(e.scores)
-                tv_j, hit = e.table_version, True
-            else:
-                tools, scores = miss_tools[m], miss_scores[m]
-                tv_j, hit = table_version, False
-                m += 1
-            out.append(
-                RouteResult(
-                    tools=tools,
-                    scores=scores,
-                    latency_ms=latency_ms,
-                    pool=self.pool_selector(q[j], tools),
-                    table_version=tv_j,
-                    stage_version=stage_version,
-                    cache_hit=hit,
+        with spans.span("route.assemble") as assemble:
+            latency_ms = clock.duration_ms(t0) / n_q
+            # a mask can leave fewer than k candidates; those slots carry
+            # the NEG_INF sentinel and must not surface as selected tools
+            miss_tools: List[List[int]] = []
+            miss_scores: List[List[float]] = []
+            for m in range(n_miss):
+                valid_m = top_scores[m] > NEG_INF / 2
+                miss_tools.append([int(t) for t in top_idx[m][valid_m]])
+                miss_scores.append([float(s) for s in top_scores[m][valid_m]])
+            if use_cache and n_miss:
+                # fresh decisions enter the cache stamped with the versions
+                # that actually produced them: the topk snapshot's
+                # table_version plus the batch's stage snapshot — NOT
+                # tv_live, which a mid-batch swap may already have left
+                # behind
+                cache.insert_batch(
+                    q_miss, miss_tools, miss_scores,
+                    table_version=table_version, stage_version=stage_version,
                 )
-            )
+            out = []
+            m = 0
+            for j in range(n_q):
+                e = cached[j] if use_cache else None
+                if e is not None:
+                    tools, scores = list(e.tools), list(e.scores)
+                    tv_j, hit = e.table_version, True
+                else:
+                    tools, scores = miss_tools[m], miss_scores[m]
+                    tv_j, hit = table_version, False
+                    m += 1
+                out.append(
+                    RouteResult(
+                        tools=tools,
+                        scores=scores,
+                        latency_ms=latency_ms,
+                        pool=self.pool_selector(q[j], tools),
+                        table_version=tv_j,
+                        stage_version=stage_version,
+                        cache_hit=hit,
+                    )
+                )
         if timed:
-            t_done = clock.perf()
-            # spans exist only for work that actually ran: the cache span
-            # only when a cache is attached, adapter/score only when misses
-            # reached the index, the rerank span only when the Stage-2 MLP
-            # actually ran — recording ~0 ms slice-only "reranks" (or
-            # all-hit "scores") would poison the p50
-            spans = [("embed", (t_embed - t0) * 1e3)]
-            if use_cache:
-                spans.append(("cache", (t_cache - t_embed) * 1e3))
-            if n_miss:
-                spans.append(("adapter", (t_adapter - t_cache) * 1e3))
-                spans.append(("score", (t_score - t_adapter) * 1e3))
-                if rerank:
-                    spans.append(("rerank", (t_rank - t_score) * 1e3))
-            spans.append(("assemble", (t_done - t_rank) * 1e3))
-            total_ms = (t_done - t0) * 1e3
-            # trace BEFORE metrics: a sampled batch's trace id becomes the
-            # exemplar on the duration buckets it lands in, so a p99 reading
-            # links straight to a concrete RouteTrace ("/slo" and
-            # `repro-obs watch` render that link)
-            trace = None
-            if tracing:
-                trace = self._tracer.record(
-                    batch_size=n_q,
+            # the gateway's own telemetry is a span too: route_obs_ms,
+            # recorded after it closes, times everything but itself
+            with spans.span("route.telemetry") as telemetry:
+                self._record_batch(
+                    spans, tracing, n_q,
                     # the bucket is what the jitted programs compiled for:
                     # the padded MISS block (an all-hit batch never reached
                     # them and reports bucket 0 under path "cache")
@@ -587,40 +592,78 @@ class SemanticRouter:
                     path="cache" if not n_miss else self.index.last_path(),
                     table_version=table_version,
                     stage_version=stage_version,
-                    spans=spans,
-                    total_ms=total_ms,
+                    total_ms=(assemble.t1 - t0) * 1e3,
+                    top_scores=top_scores,
                 )
             if obs is not None:
-                exemplar = trace.trace_id if trace is not None else None
-                obs.requests.inc(n_q)
-                obs.batches.inc()
-                obs.batch_size.record(float(n_q))
-                obs.batch_ms.record(total_ms, exemplar=exemplar)
-                phase = obs.phase
-                for name, ms in spans:
-                    phase[name].record(ms, exemplar=exemplar)
-                obs.table_version.set(table_version)
-                obs.stage_version.set(stage_version)
-                if top_scores.shape[1] >= 2:
-                    # sampled 1-in-4 batches: the gap histogram feeds
-                    # percentile summaries (confidence()), which a quarter
-                    # of the traffic estimates as well as all of it — and
-                    # this is the priciest per-batch obs block (a vectorized
-                    # pass + record_many). Racy tick increment is fine: the
-                    # sampling needs to be approximate, not exact.
-                    self._gap_tick += 1
-                    if self._gap_tick % 4 == 0:
-                        # rows with < 2 valid candidates carry the NEG_INF
-                        # sentinel in slot 1 and are skipped
-                        valid2 = top_scores[:, 1] > NEG_INF / 2
-                        if np.any(valid2):
-                            gaps = top_scores[:, 0] - top_scores[:, 1]
-                            obs.score_gap.record_many(gaps[valid2])
+                obs.obs_ms.record(telemetry.ms)
         if self._quality is not None:
             # raw pre-adapter embeddings, unpadded rows: drift is about the
             # query population vs the live table, not about learned stages
             self._quality.observe_queries(q)
         return out
+
+    def _record_batch(
+        self,
+        spans: SpanRecorder,
+        tracing: bool,
+        n_q: int,
+        bucket: int,
+        path: str,
+        table_version: int,
+        stage_version: int,
+        total_ms: float,
+        top_scores: np.ndarray,
+    ) -> None:
+        """One batch's spans into its sampled trace and the histograms."""
+        obs = self._obs
+        # trace BEFORE metrics: a sampled batch's trace id becomes the
+        # exemplar on the duration buckets it lands in, so a p99 reading
+        # links straight to a concrete RouteTrace ("/slo" and
+        # `repro-obs watch` render that link)
+        trace = None
+        if tracing:
+            trace = self._tracer.record(
+                batch_size=n_q,
+                bucket=bucket,
+                path=path,
+                table_version=table_version,
+                stage_version=stage_version,
+                spans=[(name.removeprefix("route."), ms) for name, ms in spans.spans],
+                total_ms=total_ms,
+                ts=spans.entry_wall(),
+            )
+        if obs is None:
+            return
+        exemplar = trace.trace_id if trace is not None else None
+        obs.requests.inc(n_q)
+        obs.batches.inc()
+        obs.batch_size.record(float(n_q))
+        obs.batch_ms.record(total_ms, exemplar=exemplar)
+        phase = obs.phase
+        for name, ms in spans.under("route."):
+            phase[name].record(ms, exemplar=exemplar)
+        step = obs.index_step
+        for name, ms in spans.under("index."):
+            step[name].record(ms)
+        if spans.h2d_bytes:
+            obs.transfer_bytes["h2d"].inc(spans.h2d_bytes)
+            obs.transfer_bytes["d2h"].inc(spans.d2h_bytes)
+        if top_scores.shape[1] >= 2:
+            # sampled 1-in-4 batches: the gap histogram feeds percentile
+            # summaries (confidence()), which a quarter of the traffic
+            # estimates as well as all of it — and this is the priciest
+            # per-batch obs block (a vectorized pass + record_many). Racy
+            # tick increment is fine: the sampling needs to be approximate,
+            # not exact.
+            self._gap_tick += 1
+            if self._gap_tick % 4 == 0:
+                # rows with < 2 valid candidates carry the NEG_INF sentinel
+                # in slot 1 and are skipped
+                valid2 = top_scores[:, 1] > NEG_INF / 2
+                if np.any(valid2):
+                    gaps = top_scores[:, 0] - top_scores[:, 1]
+                    obs.score_gap.record_many(gaps[valid2])
 
     def route(
         self,

@@ -49,7 +49,7 @@ from repro.obs import (
 from repro.obs.flightrec import DUMP_FORMAT_VERSION
 from repro.obs.report import main as report_main
 from repro.obs.slo import SLO, BurnWindow
-from repro.router.gateway import SemanticRouter, hot_path_jits
+from repro.router.gateway import INDEX_STEPS, PHASES, SemanticRouter, hot_path_jits
 from repro.router.stages import StageSet
 from repro.router.tooldb import ToolRecord, ToolsDatabase
 
@@ -463,8 +463,8 @@ def test_concurrent_slo_traces_dumps_scrapes_during_swaps(tmp_path):
                 # stamps are internally consistent: versions the db/router
                 # actually passed through, never torn/interleaved values
                 assert 0 <= t["table_version"] <= db.table_version
-                assert set(t["spans"]) <= {
-                    "embed", "adapter", "score", "rerank", "assemble"
+                assert set(t["spans"]) <= set(PHASES) | {
+                    f"index.{step}" for step in INDEX_STEPS
                 }
 
         def check_dumps(body):

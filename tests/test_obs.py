@@ -191,7 +191,7 @@ def test_default_registry_is_process_wide():
 
 def test_counters_exact_under_concurrent_route_batch():
     reg = MetricsRegistry()
-    router, db = _make_router(metrics=reg)
+    router, _ = _make_router(metrics=reg)
     n_threads, n_calls, batch = 8, 25, 4
     queries = [np.arange(j, j + 4) for j in range(batch)]
     errors = []
@@ -213,11 +213,15 @@ def test_counters_exact_under_concurrent_route_batch():
     assert reg.counter("route_batches_total").value() == total
     assert reg.counter("route_requests_total").value() == total * batch
     assert reg.histogram("route_batch_ms").count() == total
-    for phase in ("embed", "adapter", "score", "assemble"):
+    for phase in ("embed", "pad", "score", "assemble"):
         assert reg.histogram("route_phase_ms", phase=phase).count() == total
-    # no Stage-2 MLP configured: slice-only "reranks" must not be recorded
+    # no learned stages configured: identity "adapters" and slice-only
+    # "reranks" must not be recorded
+    assert reg.histogram("route_phase_ms", phase="adapter").count() == 0
     assert reg.histogram("route_phase_ms", phase="rerank").count() == 0
-    assert reg.gauge("route_table_version").value() == db.table_version
+    for step in ("snapshot", "upload", "dispatch", "wait", "fetch"):
+        assert reg.histogram("index_step_ms", step=step).count() == total
+    assert reg.histogram("route_obs_ms").count() == total
 
 
 # ------------------------------------------------------------------ EventBus
@@ -278,15 +282,19 @@ def test_tracer_ring_export_and_report(tmp_path):
     t = traces[-1]
     assert t.batch_size == 2 and t.bucket == 2  # pow2 bucket of Q=2
     assert t.path == "index:dense"
-    phases = [name for name, _ in t.spans]
-    assert phases == ["embed", "adapter", "score", "assemble"]  # no MLP
-    assert t.total_ms >= sum(ms for _, ms in t.spans) * 0.5
+    names = [name for name, _ in t.spans]
+    steps = ["index.snapshot", "index.upload", "index.dispatch", "index.wait",
+             "index.fetch"]
+    # start order; no learned stages; the index steps lie inside score
+    assert names == ["embed", "pad", "score", *steps, "assemble"]
+    phases = [n for n in names if not n.startswith("index.")]
+    assert t.total_ms >= sum(ms for n, ms in t.spans if n in phases) * 0.5
     assert "embed" in tracer.phase_summaries()
 
     out = tmp_path / "trace.jsonl"
     assert tracer.export_jsonl(str(out)) == 8
     records = [json.loads(line) for line in out.read_text().splitlines()]
-    assert len(records) == 8 and records[0]["spans"].keys() == set(phases)
+    assert len(records) == 8 and records[0]["spans"].keys() == set(names)
     report = render_trace_report(records)
     assert "8 traces" in report
     assert "index:dense=8" in report
