@@ -10,6 +10,11 @@ implementations share one interface:
     similarity+top-K Pallas kernel for pod-co-located routers (DESIGN.md §4).
 
 Candidate masking supports MetaTool-style per-query candidate subsets.
+
+The served programs (`topk_dense`, the Pallas backend's `topk_sim_packed`)
+return their top-K as one packed block (`pack_topk`), so a call costs one
+device-to-host copy; `unpack_topk` splits it on the host without copying.
+`topk_sim` itself keeps its (scores, indices) pair.
 """
 from __future__ import annotations
 
@@ -19,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["similarities", "rank_dense", "topk_dense"]
+__all__ = ["similarities", "rank_dense", "topk_dense", "pack_topk", "unpack_topk"]
 
 NEG_INF = -1e30
 
@@ -35,14 +40,33 @@ def similarities(query_emb: jnp.ndarray, tool_emb: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(query_emb, tool_emb.T, precision=jax.lax.Precision.HIGHEST)
 
 
+def pack_topk(scores: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """In-jit: (scores [Q, k] float32, indices [Q, k]) -> one [Q, 2k] int32
+    block, the scores' bits first, then the indices.
+
+    Packed as int32, never as float32: small indices bitcast to float32
+    are subnormals, which a TPU may flush to zero."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return jnp.concatenate([bits, idx.astype(jnp.int32)], axis=1)
+
+
+def unpack_topk(block, k: int) -> tuple[np.ndarray, np.ndarray]:
+    """Host side of `pack_topk`: (scores [Q, k] float32, indices [Q, k]
+    int32), views of the one host copy of `block`, bit for bit."""
+    block = np.asarray(block)
+    return block[:, :k].view(np.float32), block[:, k:]
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def topk_dense(
     query_emb: jnp.ndarray,
     tool_emb: jnp.ndarray,
     k: int,
     candidate_mask: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Top-k (scores, indices) per query. candidate_mask: [Q,T] {0,1} or None.
+) -> jnp.ndarray:
+    """Top-k per query as one packed [Q, 2k] int32 block (`pack_topk`;
+    `unpack_topk` gives (scores, indices)). candidate_mask: [Q,T] {0,1} or
+    None.
 
     The device ops carry their step in the op metadata (`score/`, `topk/`)."""
     with jax.named_scope("score"):
@@ -50,7 +74,8 @@ def topk_dense(
         if candidate_mask is not None:
             sims = jnp.where(candidate_mask > 0, sims, NEG_INF)
     with jax.named_scope("topk"):
-        return jax.lax.top_k(sims, k)
+        scores, idx = jax.lax.top_k(sims, k)
+    return pack_topk(scores, idx)
 
 
 def rank_dense(
@@ -60,10 +85,10 @@ def rank_dense(
     candidate_mask: np.ndarray | None = None,
 ) -> np.ndarray:
     """Numpy convenience wrapper returning indices only."""
-    _, idx = topk_dense(
+    block = topk_dense(
         jnp.asarray(query_emb),
         jnp.asarray(tool_emb),
         k,
         None if candidate_mask is None else jnp.asarray(candidate_mask),
     )
-    return np.asarray(idx)
+    return unpack_topk(block, k)[1]

@@ -26,8 +26,11 @@ Contract (`topk`):
     the exact dense fallback instead of calling them with one.
 
 The device backends (dense, pallas, and the manager's exact fallback, a
-`DenseBackend`) share one host round trip, `round_trip`, which times its
-steps as spans of the calling batch (`repro.obs.trace.current_spans`).
+`DenseBackend`) share one host round trip, `round_trip`: their jitted
+program returns the top-K as one packed `[Q, 2k]` int32 block
+(`core.retrieval.pack_topk`), so each call makes one copy up (two with a
+mask) and one copy back. It times its steps as spans of the calling batch
+(`repro.obs.trace.current_spans`).
 """
 from __future__ import annotations
 
@@ -37,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.retrieval import NEG_INF
+from repro.core.retrieval import NEG_INF, unpack_topk
 from repro.obs.trace import current_spans
 
 __all__ = ["NEG_INF", "ScorerBackend", "round_trip"]
@@ -63,43 +66,40 @@ class ScorerBackend(Protocol):
 
 
 def round_trip(
-    fn: Callable[[jax.Array, Optional[jax.Array]], Tuple[jax.Array, jax.Array]],
+    fn: Callable[[jax.Array, Optional[jax.Array]], jax.Array],
     queries: np.ndarray,
     candidate_mask: Optional[np.ndarray] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """One device top-K call from the host: upload, dispatch, wait, fetch.
+    """One device top-K call from the host: upload, dispatch, wait.
 
     `fn(queries, mask)` is the jitted scorer over the backend's
-    device-resident table. Each step is a span of the calling batch:
+    device-resident table; it returns one packed `[Q, 2k]` int32 block
+    (`pack_topk`), which comes back in one copy and is split on the host
+    into (scores, indices) views (`unpack_topk`), bit for bit the
+    program's. Each step is a span of the calling batch:
 
       index.upload    the query block (and mask) to device arrays;
-      index.dispatch  the jitted call, until it returns its futures;
-      index.wait      until the scores are on the host: the device's queue
-                      and run, then the scores' copy back;
-      index.fetch     the indices' copy back.
+      index.dispatch  the jitted call, until it returns its future;
+      index.wait      until the block is on the host: the device's queue
+                      and run, then its one copy back.
 
     Dispatch is asynchronous, so the device's transfer and compute time
-    land in `index.wait`. The two results are copies of the same size
-    (Q x k x 4 bytes), so `index.fetch` is one copy's cost and the wait
-    proper is `index.wait` less it. No explicit wait is added to split
-    them further: on a TPU v5e a `block_until_ready` before the copies
-    cost ~0.11 ms a call, ~6% of the whole round trip. The
-    computation and its transfers are the plain call's; the bytes moved
-    (the padded query block and mask up, scores and indices down) go to
-    the batch's recorder.
+    land in `index.wait`. No explicit wait is added to split it further:
+    on a TPU v5e a `block_until_ready` before the copy cost ~0.11 ms a
+    call, ~6% of the whole round trip. The copies and their bytes (the
+    padded query block and mask up, the block down: Q x k x 8 bytes) go
+    to the batch's recorder.
     """
     spans = current_spans()
     with spans.span("index.upload"):
         q = jnp.asarray(queries)
         mask = None if candidate_mask is None else jnp.asarray(candidate_mask)
     with spans.span("index.dispatch"):
-        scores, idx = fn(q, mask)
+        block = fn(q, mask)
     with spans.span("index.wait"):
-        scores = np.asarray(scores)
-    with spans.span("index.fetch"):
-        idx = np.asarray(idx)
-    up = q.size * q.dtype.itemsize  # what `jax.Array.nbytes` computes, cheaper
+        block = np.asarray(block)
+    # what `jax.Array.nbytes` computes, cheaper
+    spans.transfer(h2d=q.size * q.dtype.itemsize, d2h=block.nbytes)
     if mask is not None:
-        up += mask.size * mask.dtype.itemsize
-    spans.transfer(h2d=up, d2h=scores.nbytes + idx.nbytes)
-    return scores, idx
+        spans.transfer(h2d=mask.size * mask.dtype.itemsize)
+    return unpack_topk(block, block.shape[1] // 2)

@@ -1,10 +1,11 @@
 """DenseBackend: exact brute-force scoring — the default and the oracle.
 
-This is the PR 1 serving path verbatim: one jitted `topk_dense` call (matmul
-+ `lax.top_k`, optional per-query candidate masks) against a device-resident
-copy of the table snapshot. It exists as a backend so the gateway stops
-hardcoding it: the numerics are unchanged, only the ownership of the device
-copy moved from `SemanticRouter._device_table` into the index layer.
+This is the gateway's first serving path: one jitted `topk_dense` call
+(matmul + `lax.top_k`, optional per-query candidate masks, the result packed
+into one block) against a device-resident copy of the table snapshot. It exists as a
+backend so the gateway stops hardcoding it: the numerics are unchanged, only
+the ownership of the device copy moved from `SemanticRouter._device_table`
+into the index layer.
 
 Per-query cost is O(T·D) — at MCP-registry scale (100k tools) that is the
 brute-force wall `IVFBackend` exists to avoid; dense remains the fallback

@@ -36,7 +36,8 @@ Each `topk` call's snapshot, backend lookup and version check is the
 `index.snapshot` span of the calling batch (`repro.obs.trace`); the
 backend's own round trip times the rest. The table each build uploads
 (`upload_bytes` of a device backend) counts into
-`index_transfer_bytes_total{dir=h2d}`, so a swap's upload shows there.
+`index_transfer_bytes_total{dir=h2d}`, and as one copy into
+`index_transfers_total{dir=h2d}`, so a swap's upload shows there.
 """
 from __future__ import annotations
 
@@ -68,10 +69,14 @@ class _IndexInstruments:
         self.build_failures = registry.counter("index_build_failures_total")
         self.build_ms = registry.histogram("index_build_ms")
         self.h2d_bytes = registry.counter("index_transfer_bytes_total", dir="h2d")
+        self.h2d_copies = registry.counter("index_transfers_total", dir="h2d")
 
     def uploaded(self, backend) -> None:
         """Count the table a device backend uploaded when it was built."""
-        self.h2d_bytes.inc(getattr(backend, "upload_bytes", 0))
+        nbytes = getattr(backend, "upload_bytes", 0)
+        if nbytes:
+            self.h2d_bytes.inc(nbytes)
+            self.h2d_copies.inc()
 
 
 def _build_backend(kind: str, table: np.ndarray, table_version: int, **opts):

@@ -17,18 +17,38 @@ on that.
 No candidate-mask support: the kernel scores every table row by design
 (masks would break its streaming tile layout). The manager's exact fallback
 covers masked batches.
+
+It serves through `topk_sim_packed`: `topk_sim` and the packing of its
+result into one block (`core.retrieval.pack_topk`) in one jitted program,
+so a call makes one device-to-host copy.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.retrieval import pack_topk
 from repro.index.base import round_trip
 from repro.kernels.topk_sim.ops import topk_sim
 
-__all__ = ["PallasBackend"]
+__all__ = ["PallasBackend", "topk_sim_packed"]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
+def topk_sim_packed(
+    queries: jnp.ndarray,
+    table: jnp.ndarray,
+    k: int,
+    use_pallas: Optional[bool] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """`topk_sim`'s (scores, indices) as one packed [Q, 2k] int32 block."""
+    return pack_topk(*topk_sim(queries, table, k, use_pallas=use_pallas,
+                               interpret=interpret))
 
 
 class PallasBackend:
@@ -63,7 +83,7 @@ class PallasBackend:
             "fallback"
         )
         return round_trip(
-            lambda q, _mask: topk_sim(
+            lambda q, _mask: topk_sim_packed(
                 q, self._table_j, k,
                 use_pallas=self.use_pallas, interpret=self.interpret,
             ),

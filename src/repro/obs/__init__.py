@@ -88,19 +88,23 @@ route_obs_ms (histogram)
     counter records, score-gap pass (recorded after it, not timing itself).
 route_batch_size (histogram)
     Raw batch sizes (pre pow2 padding).
-index_step_ms{step=snapshot|upload|dispatch|wait|fetch|ivf} (histogram)
+index_step_ms{step=snapshot|upload|dispatch|wait|ivf} (histogram)
     Per-call duration of each index-layer step inside the `score` phase:
     the manager's snapshot + backend lookup, then the device backends' host
     round trip (`repro.index.base.round_trip`) — query upload, jitted
-    dispatch, `wait` until the scores are on the host (the device's queue
-    and run land here: dispatch is asynchronous; then the scores' copy),
-    `fetch` of the indices (one copy of the same size); or the IVF
-    backend's one host span. Recorded by the gateway with its phases.
+    dispatch, `wait` until the packed top-K block is on the host (the
+    device's queue and run land here: dispatch is asynchronous; then the
+    block's one copy); or the IVF backend's one host span. Recorded by the
+    gateway with its phases.
 index_transfer_bytes_total{dir=h2d|d2h} (counter)
     Bytes moved between host and device by the index layer: per call the
-    padded query block (+ mask) up and scores + indices down (gateway
-    registry), per build the table a device backend uploads (manager
-    registry; the same one by default).
+    padded query block (+ mask) up and the packed scores + indices down
+    (gateway registry), per build the table a device backend uploads
+    (manager registry; the same one by default).
+index_transfers_total{dir=h2d|d2h} (counter)
+    Host-device copies the index layer makes, beside those bytes: per
+    device call one up (two with a mask) and one down, the packed block
+    (gateway registry); per build one up (manager registry).
 route_outcomes_dropped_total (counter)
     Outcome-ring overwrites in `record_outcome` (undrained router).
 route_cache_hits_total / route_cache_misses_total (counter)
@@ -151,8 +155,7 @@ Profiler span catalog (`SpanRecorder`, while a profiler trace is active)
 route.embed, route.cache, route.pad, route.adapter, route.score,
 route.rerank, route.assemble, route.telemetry
     The gateway's phases (histogram label: the name after ``route.``).
-index.snapshot, index.upload, index.dispatch, index.wait, index.fetch,
-index.ivf
+index.snapshot, index.upload, index.dispatch, index.wait, index.ivf
     The index layer's steps, inside ``route.score``.
 
 Device ops carry their step in the op metadata: ``score/`` and ``topk/``

@@ -3,7 +3,7 @@
 `SpanRecorder` is the one span primitive of the route path. `route_batch`
 opens one per batch; its phases (``route.embed`` ... ``route.telemetry``)
 and the index layer's steps below it (``index.snapshot`` ...
-``index.fetch``, ``index.ivf``) are ``with spans.span(name):`` blocks.
+``index.wait``, ``index.ivf``) are ``with spans.span(name):`` blocks.
 Each stamps `clock.perf()` at both ends. While a profiler trace is active
 (checked once per batch) each span also enters a
 `jax.profiler.TraceAnnotation` of the same name, so it lies on the
@@ -107,11 +107,12 @@ class SpanRecorder:
     metrics, no sampled trace) makes every span a no-op unless a profiler
     trace is active. `bound()` makes the recorder the calling thread's
     `current_spans()`: that is how the index layer records into the batch
-    that called it without a parameter, and adds the bytes it moves between
-    host and device with `transfer`.
+    that called it without a parameter, and adds the copies it makes
+    between host and device, and their bytes, with `transfer`.
     """
 
-    __slots__ = ("t0", "profiling", "enabled", "h2d_bytes", "d2h_bytes", "_spans")
+    __slots__ = ("t0", "profiling", "enabled", "h2d_bytes", "d2h_bytes",
+                 "h2d_copies", "d2h_copies", "_spans")
 
     def __init__(self, enabled: bool = True, t0: Optional[float] = None):
         self.t0 = clock.perf() if t0 is None else t0
@@ -119,6 +120,7 @@ class SpanRecorder:
         self.enabled = enabled or self.profiling
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        self.h2d_copies = self.d2h_copies = 0
         self._spans: List[_Span] = []
 
     def span(self, name: str, start: Optional[float] = None):
@@ -131,8 +133,13 @@ class SpanRecorder:
         return span
 
     def transfer(self, h2d: int = 0, d2h: int = 0) -> None:
-        self.h2d_bytes += int(h2d)
-        self.d2h_bytes += int(d2h)
+        """One copy of `h2d` bytes up and/or one of `d2h` bytes down."""
+        if h2d:
+            self.h2d_bytes += int(h2d)
+            self.h2d_copies += 1
+        if d2h:
+            self.d2h_bytes += int(d2h)
+            self.d2h_copies += 1
 
     def entry_wall(self) -> float:
         """The entry stamp `t0` on the wall clock (for exported records)."""
@@ -177,6 +184,7 @@ class _NullRecorder(SpanRecorder):
         self.t0 = 0.0
         self.profiling = self.enabled = False
         self.h2d_bytes = self.d2h_bytes = 0
+        self.h2d_copies = self.d2h_copies = 0
         self._spans = []
 
     def transfer(self, h2d: int = 0, d2h: int = 0) -> None:

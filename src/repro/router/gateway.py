@@ -87,7 +87,7 @@ __all__ = [
 PHASES = ("embed", "cache", "pad", "adapter", "score", "rerank", "assemble")
 # index-layer steps inside the score phase (`repro.index.base.round_trip`,
 # the manager's snapshot, the IVF backend's one host span)
-INDEX_STEPS = ("snapshot", "upload", "dispatch", "wait", "fetch", "ivf")
+INDEX_STEPS = ("snapshot", "upload", "dispatch", "wait", "ivf")
 
 
 def hot_path_jits() -> "OrderedDict[str, Callable]":
@@ -134,6 +134,10 @@ class _GatewayInstruments:
         }
         self.transfer_bytes = {
             d: registry.counter("index_transfer_bytes_total", dir=d)
+            for d in ("h2d", "d2h")
+        }
+        self.transfers = {
+            d: registry.counter("index_transfers_total", dir=d)
             for d in ("h2d", "d2h")
         }
         self.obs_ms = registry.histogram("route_obs_ms")
@@ -646,9 +650,11 @@ class SemanticRouter:
         step = obs.index_step
         for name, ms in spans.under("index."):
             step[name].record(ms)
-        if spans.h2d_bytes:
+        if spans.h2d_copies:
             obs.transfer_bytes["h2d"].inc(spans.h2d_bytes)
             obs.transfer_bytes["d2h"].inc(spans.d2h_bytes)
+            obs.transfers["h2d"].inc(spans.h2d_copies)
+            obs.transfers["d2h"].inc(spans.d2h_copies)
         if top_scores.shape[1] >= 2:
             # sampled 1-in-4 batches: the gap histogram feeds percentile
             # summaries (confidence()), which a quarter of the traffic

@@ -219,8 +219,10 @@ def test_counters_exact_under_concurrent_route_batch():
     # "reranks" must not be recorded
     assert reg.histogram("route_phase_ms", phase="adapter").count() == 0
     assert reg.histogram("route_phase_ms", phase="rerank").count() == 0
-    for step in ("snapshot", "upload", "dispatch", "wait", "fetch"):
+    for step in ("snapshot", "upload", "dispatch", "wait"):
         assert reg.histogram("index_step_ms", step=step).count() == total
+    # one copy back per index call: the packed top-K block
+    assert reg.counter("index_transfers_total", dir="d2h").value() == total
     assert reg.histogram("route_obs_ms").count() == total
 
 
@@ -283,8 +285,7 @@ def test_tracer_ring_export_and_report(tmp_path):
     assert t.batch_size == 2 and t.bucket == 2  # pow2 bucket of Q=2
     assert t.path == "index:dense"
     names = [name for name, _ in t.spans]
-    steps = ["index.snapshot", "index.upload", "index.dispatch", "index.wait",
-             "index.fetch"]
+    steps = ["index.snapshot", "index.upload", "index.dispatch", "index.wait"]
     # start order; no learned stages; the index steps lie inside score
     assert names == ["embed", "pad", "score", *steps, "assemble"]
     phases = [n for n in names if not n.startswith("index.")]

@@ -7,8 +7,9 @@
   active, every span is one, and a real CPU profiler trace read back by the
   benchmark's reader holds each span, properly nested, at its recorded
   length;
-* transfer bytes per call for dense, the exact fallback and
-  Pallas-interpret, and the table upload of a build;
+* transfer bytes and copies per call for dense, the exact fallback and
+  Pallas-interpret (one copy back: the packed top-K block), and the table
+  upload of a build;
 * the benchmark's trace reduction with program spans nested inside its
   own: device sums unchanged, idle gaps named after the innermost span;
 * the device ops of the score and re-rank programs carry their step in
@@ -39,7 +40,7 @@ sys.path.insert(0, str(ROOT))
 from bench import trace_reduce as tr  # noqa: E402
 
 D, T, K = 16, 12, 3
-ROUND_TRIP = ("snapshot", "upload", "dispatch", "wait", "fetch")
+ROUND_TRIP = ("snapshot", "upload", "dispatch", "wait")
 PROGRAM_SPANS = tuple(f"route.{p}" for p in PHASES + ("telemetry",)) + tuple(
     f"index.{s}" for s in INDEX_STEPS
 )
@@ -251,9 +252,12 @@ def test_transfer_bytes_per_call(backend, opts, masked):
     path = router.index.last_path()
     assert path == ("exact" if masked else f"index:{backend}")
     up = Q_PAD * D * 4 + (Q_PAD * T * 4 if masked else 0)
-    down = Q_PAD * K * 8  # float32 scores + int32 indices
+    down = Q_PAD * K * 8  # float32 scores + int32 indices, in one block
     assert reg.counter("index_transfer_bytes_total", dir="h2d").value() == up
     assert reg.counter("index_transfer_bytes_total", dir="d2h").value() == down
+    # the query block (and the mask) up, the packed block down
+    assert reg.counter("index_transfers_total", dir="h2d").value() == 1 + masked
+    assert reg.counter("index_transfers_total", dir="d2h").value() == 1
 
 
 def test_build_uploads_count_the_table():
@@ -263,11 +267,14 @@ def test_build_uploads_count_the_table():
     table = rng.standard_normal((T, D)).astype(np.float32)
     db = ToolsDatabase(records, table)
     h2d = reg.counter("index_transfer_bytes_total", dir="h2d")
+    copies = reg.counter("index_transfers_total", dir="h2d")
     ToolIndexManager(db, backend="dense", async_rebuild=False, metrics=reg)
     probe = min(T, 64) * D * 4  # the validation build at construction
     assert h2d.value() == probe + T * D * 4
+    assert copies.value() == 2
     db.swap_table(table[::-1].copy(), expect_current=db.table_version)
     assert h2d.value() == probe + 2 * T * D * 4
+    assert copies.value() == 3
 
 
 def test_ivf_is_one_host_span_and_moves_no_bytes():
@@ -279,6 +286,7 @@ def test_ivf_is_one_host_span_and_moves_no_bytes():
     assert reg.histogram("index_step_ms", step="ivf").count() == 1
     assert reg.histogram("index_step_ms", step="wait").count() == 0
     assert reg.counter("index_transfer_bytes_total", dir="h2d").value() == 0
+    assert reg.counter("index_transfers_total", dir="d2h").value() == 0
 
 
 # ---------------------------------------- the benchmark's trace reduction
@@ -293,10 +301,10 @@ BENCH_SPANS = [
 PROGRAM = [
     (105, 205, "route.embed"), (205, 610, "route.score"),
     (212, 220, "index.snapshot"), (220, 240, "index.upload"), (240, 250, "index.dispatch"),
-    (250, 580, "index.wait"), (580, 600, "index.fetch"), (611, 618, "route.assemble"),
+    (250, 600, "index.wait"), (611, 618, "route.assemble"),
     (705, 800, "route.embed"), (805, 895, "route.score"),
     (812, 820, "index.snapshot"), (820, 840, "index.upload"), (840, 850, "index.dispatch"),
-    (850, 870, "index.wait"), (870, 885, "index.fetch"), (896, 899, "route.assemble"),
+    (850, 885, "index.wait"), (896, 899, "route.assemble"),
 ]
 
 
@@ -322,13 +330,14 @@ def test_program_spans_leave_device_sums_alone_and_name_the_gaps():
     assert both.device_s["index.dispatch"] == pytest.approx(105e-9)  # both enqueued there
     # the same gaps, named after the innermost span over most of each:
     # before `a` the client waited; before `b` batch 1 sat in its wait for
-    # the device ([250, 580] of [300, 845]); the tail is the client's again
+    # the device and its copy back ([250, 600] of [300, 845]); the tail is
+    # the client's again
     assert [g for _, g in both.idle_gaps] == [g for _, g in plain.idle_gaps]
     assert [n for n, _ in plain.idle_gaps] == ["index.topk", "window", "window"]
     assert [n for n, _ in both.idle_gaps] == ["index.wait", "window", "window"]
     segs = tr.flatten(BENCH_SPANS + PROGRAM)
     starts = [s for s, _, _ in segs]
-    assert tr.label(580, 600, segs, starts) == "index.fetch"
+    assert tr.label(580, 600, segs, starts) == "index.wait"  # the one copy back
     assert tr.label(110, 200, segs, starts) == "embed_batch_fn"  # inside route.embed
     assert tr.label(605, 610, segs, starts) == "route.score"
 

@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.retrieval import topk_dense
+from repro.index.pallas_backend import topk_sim_packed
 from repro.kernels.topk_sim.kernel import topk_sim_pallas
 from repro.models import model as M
 
@@ -84,6 +85,29 @@ def test_topk_dense_compiles_for_v5e(one_chip):
         _struct((100_000, D), jnp.float32, one_chip),
         5,
     ).compile()
+    assert _fits(compiled)
+
+
+@pytest.mark.parametrize(
+    "program,q,t,k",
+    [
+        ("topk_dense", 64, 2413, 5),  # the benchmark cells' shape
+        ("topk_sim_packed", 128, 2413, 5),  # the Pallas backend's served program
+    ],
+)
+def test_packed_topk_programs_compile_for_v5e(one_chip, program, q, t, k):
+    queries = _struct((q, D), jnp.float32, one_chip)
+    table = _struct((t, D), jnp.float32, one_chip)
+    if program == "topk_dense":
+        lowered = topk_dense.lower(queries, table, k)
+    else:
+        lowered = topk_sim_packed.lower(queries, table, k, use_pallas=True)
+    out = lowered.out_info
+    assert (out.shape, out.dtype) == ((q, 2 * k), jnp.int32)  # one packed block
+    compiled = lowered.compile()
+    if program == "topk_sim_packed":
+        # packing in the same program did not push the kernel out
+        assert "tpu_custom_call" in compiled.as_text()
     assert _fits(compiled)
 
 
