@@ -24,6 +24,7 @@ from repro.core.features import OutcomeFeaturizer
 from repro.core.refine import RefineConfig, RefineResult, refine_with_gate
 from repro.data.benchmarks import Benchmark
 from repro.embedding.bag_encoder import BagEncoder
+from repro.obs.metrics import get_registry
 
 __all__ = ["PipelineConfig", "OATSPipeline", "STAGE_PRESETS"]
 
@@ -126,6 +127,13 @@ class OATSPipeline:
                 None if cand_mask_all is None else jnp.asarray(cand_mask_all[val_idx]),
             )
             tool_table = np.asarray(refine_result.embeddings)
+            accepted = bool(refine_result.accepted)
+            reg = get_registry()
+            reg.counter("refine_gate_total",
+                        decision="accepted" if accepted else "rejected").inc()
+            # rows the deployed fit moved: tools with a labelled fit query
+            with_positive = int((relevance[fit_idx].sum(axis=0) > 0).sum())
+            reg.gauge("refine_rows_moved").set(with_positive if accepted else 0)
 
         # ---- Stage 2: MLP re-ranker over outcome features
         mlp_params = None

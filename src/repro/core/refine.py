@@ -1,7 +1,8 @@
 """OATS-S1: iterative outcome-guided embedding refinement (Alg. 1, §4.1).
 
 The paper's core contribution. Pure JAX: one jitted function runs all N
-iterations (outcome collection -> centroid interpolation -> momentum blend),
+passes (outcome collection -> centroid interpolation -> momentum blend),
+unrolled at trace time (N is static),
 and a separate validation gate (Alg. 1 step 5) accepts the refined table only
 if held-out Recall@K improves. Shardable over the tool axis for very large
 tool databases (the [T, D] table and all [Q, T] masks are embarrassingly
@@ -11,7 +12,7 @@ Update rule (Eq. 7), per tool i with |Q_i^+| >= 1:
 
     e_hat = (1 - alpha) * e + alpha * centroid(Q_i^+) - beta * centroid(Q_i^-)
     e_hat = e_hat / ||e_hat||
-    e_new = mu * e_prev + (1 - mu) * e_hat        (momentum, iterations n > 1)
+    e_new = mu * e_prev + (1 - mu) * e_hat        (momentum, from the second pass on)
 
 Defaults are the paper's: alpha=0.3, beta=0.1, N=3, mu=0.5, K=5.
 """
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core.outcomes import collect_outcomes
 from repro.metrics.retrieval import batched_ndcg_at_k, batched_recall_at_k
+from repro.obs.trace import current_spans
 
 __all__ = ["RefineConfig", "RefineResult", "refine_embeddings", "refine_with_gate"]
 
@@ -100,8 +102,7 @@ def refine_embeddings(
     control plane wants for repeated refinements on large tool sets.
     """
 
-    def one_iteration(n, state):
-        e_prev, history = state
+    def one_pass(e_prev, blend):
         # Steps 1-2: outcome logs against *current* embeddings — each pass
         # exposes the new hard negatives created by the previous update.
         logs = collect_outcomes(
@@ -115,26 +116,20 @@ def refine_embeddings(
         e_hat = e_hat / jnp.maximum(jnp.linalg.norm(e_hat, axis=-1, keepdims=True), 1e-9)
         # tools with no positive outcomes stay at their previous embedding
         e_hat = jnp.where((pos_n > 0)[:, None], e_hat, e_prev)
-        # Step 4: momentum blend with previous iterate (n > 1)
+        if not blend:
+            return e_hat
+        # Step 4: momentum blend with the previous pass's table
         blended = momentum * e_prev + (1.0 - momentum) * e_hat
-        blended = blended / jnp.maximum(
-            jnp.linalg.norm(blended, axis=-1, keepdims=True), 1e-9
-        )
-        e_new = jnp.where(n > 0, blended, e_hat)
-        if keep_history:  # static: the False branch never allocates the buffer
-            history = history.at[n + 1].set(e_new)
-        return e_new, history
+        return blended / jnp.maximum(jnp.linalg.norm(blended, axis=-1, keepdims=True), 1e-9)
 
-    t, d = tool_emb.shape
-    history0 = (
-        jnp.zeros((iterations + 1, t, d), tool_emb.dtype).at[0].set(tool_emb)
-        if keep_history
-        else jnp.zeros((0,), tool_emb.dtype)
-    )
-    e_final, history = jax.lax.fori_loop(
-        0, iterations, one_iteration, (tool_emb, history0)
-    )
-    return history if keep_history else e_final
+    # `iterations` is static, so the passes are unrolled: pass 0 has no blend
+    # and the history is a stack of the pass tables. Keep the blend choice
+    # out of traced code: a `fori_loop` choosing it by a traced `n > 0`
+    # blended pass 0 too on TPU v5e.
+    tables = [tool_emb]
+    for n in range(iterations):
+        tables.append(one_pass(tables[-1], blend=n > 0))
+    return jnp.stack(tables) if keep_history else tables[-1]
 
 
 def _gate_metric_at_k(
@@ -172,32 +167,40 @@ def refine_with_gate(
     The gate guarantees the deployed system cannot degrade below the static
     baseline (§4.1) — this invariant is property-tested.
     `RefineResult.recall_before/after` hold whichever gate metric ran.
+
+    The passes and the gate are timed as spans ``fit.refine`` and
+    ``fit.gate`` of the calling thread's `current_spans()` (nothing is
+    recorded outside a bound recorder); each ends when its result is ready.
     """
-    out = refine_embeddings(
-        tool_emb,
-        train_query_emb,
-        train_relevance,
-        train_candidate_mask,
-        alpha=config.alpha,
-        beta=config.beta,
-        iterations=config.iterations,
-        momentum=config.momentum,
-        k=config.k,
-        positives=config.positives,
-        keep_history=config.keep_history,
-    )
+    spans = current_spans()
+    with spans.span("fit.refine"):
+        out = refine_embeddings(
+            tool_emb,
+            train_query_emb,
+            train_relevance,
+            train_candidate_mask,
+            alpha=config.alpha,
+            beta=config.beta,
+            iterations=config.iterations,
+            momentum=config.momentum,
+            k=config.k,
+            positives=config.positives,
+            keep_history=config.keep_history,
+        )
+        jax.block_until_ready(out)
     history = out if config.keep_history else None
     refined = out[-1] if config.keep_history else out
-    r_before = _gate_metric_at_k(
-        val_query_emb, tool_emb, val_relevance, val_candidate_mask,
-        config.k, config.gate_metric,
-    )
-    r_after = _gate_metric_at_k(
-        val_query_emb, refined, val_relevance, val_candidate_mask,
-        config.k, config.gate_metric,
-    )
-    accepted = r_after >= r_before
-    final = jnp.where(accepted, refined, tool_emb)
+    with spans.span("fit.gate"):
+        r_before = _gate_metric_at_k(
+            val_query_emb, tool_emb, val_relevance, val_candidate_mask,
+            config.k, config.gate_metric,
+        )
+        r_after = _gate_metric_at_k(
+            val_query_emb, refined, val_relevance, val_candidate_mask,
+            config.k, config.gate_metric,
+        )
+        accepted = r_after >= r_before
+        final = jax.block_until_ready(jnp.where(accepted, refined, tool_emb))
     return RefineResult(
         embeddings=final,
         accepted=accepted,

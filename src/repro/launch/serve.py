@@ -43,6 +43,7 @@ from repro.obs import (
     get_registry,
     stamp_router_costs,
 )
+from repro.obs.trace import SpanRecorder
 from repro.router.gateway import SemanticRouter
 from repro.router.latency import measure_latency, percentile_stats
 from repro.router.tooldb import ToolRecord, ToolsDatabase
@@ -74,8 +75,11 @@ def build_router(
     """
     detach = (cleanups.append if cleanups is not None else lambda fn: None)
     enc = BagEncoder(bench.vocab)
-    # offline control plane: fit the requested OATS stage, then deploy it
-    pipe = OATSPipeline.fit(bench, PipelineConfig(stages=STAGE_PRESETS[stage], k=k), enc)
+    # offline control plane: fit the requested OATS stage, then deploy it;
+    # one recorder times the fit's phases (fit.refine, fit.gate, fit.grow)
+    fit_spans = SpanRecorder()
+    with fit_spans.bound():
+        pipe = OATSPipeline.fit(bench, PipelineConfig(stages=STAGE_PRESETS[stage], k=k), enc)
     if num_tools and num_tools < bench.n_tools:
         raise SystemExit(
             f"--num-tools {num_tools} is below the native table size "
@@ -84,16 +88,17 @@ def build_router(
         )
     if num_tools and num_tools > bench.n_tools:
         base_t = bench.n_tools
-        table = scale_tool_corpus(np.asarray(pipe.tool_table), num_tools, seed=seed)
-        records = [
-            ToolRecord(
-                i,
-                f"tool_{i % base_t}" + ("" if i < base_t else f"_clone{i // base_t}"),
-                bench.desc_tokens[i % base_t],
-                int(bench.tool_category[i % base_t]),
-            )
-            for i in range(num_tools)
-        ]
+        with fit_spans.span("fit.grow"):
+            table = scale_tool_corpus(np.asarray(pipe.tool_table), num_tools, seed=seed)
+            records = [
+                ToolRecord(
+                    i,
+                    f"tool_{i % base_t}" + ("" if i < base_t else f"_clone{i // base_t}"),
+                    bench.desc_tokens[i % base_t],
+                    int(bench.tool_category[i % base_t]),
+                )
+                for i in range(num_tools)
+            ]
         db = ToolsDatabase(records, table)  # refined table baked in at scale
         if bus is not None:
             detach(bus.watch_db(db))
@@ -116,6 +121,9 @@ def build_router(
         # so version 0 is the only possible live version — the CAS still
         # guards against this block ever being reordered after serving starts
         db.swap_table(pipe.tool_table, expect_current=0)
+    reg = get_registry()
+    for phase, ms in fit_spans.under("fit."):
+        reg.histogram("fit_phase_ms", phase=phase).record(ms)
     router = SemanticRouter(
         db,
         embed_fn=lambda toks: enc.encode_one(toks),

@@ -105,6 +105,19 @@ index_transfers_total{dir=h2d|d2h} (counter)
     Host-device copies the index layer makes, beside those bytes: per
     device call one up (two with a mask) and one down, the packed block
     (gateway registry); per build one up (manager registry).
+fit_phase_ms{phase=refine|gate|grow} (histogram)
+    Host duration of each phase of `launch.serve.build_router`'s offline
+    fit, one sample per build (one `SpanRecorder` per fit): `refine` the
+    OATS-S1 passes and `gate` the validation gate (`core.refine`
+    `refine_with_gate`, each until its result is ready; stages with
+    refinement only), `grow` the tiled registry's table and tool records
+    (`scale_tool_corpus`; only when the registry grows past the fitted
+    tools).
+refine_gate_total{decision=accepted|rejected} (counter)
+    Validation-gate decisions of the offline fit (`OATSPipeline.fit`).
+refine_rows_moved (gauge)
+    Rows of the table the offline fit deployed that its passes moved: the
+    tools with at least one labelled fit query, 0 when the gate rejected.
 route_outcomes_dropped_total (counter)
     Outcome-ring overwrites in `record_outcome` (undrained router).
 route_cache_hits_total / route_cache_misses_total (counter)
@@ -157,6 +170,9 @@ route.rerank, route.assemble, route.telemetry
     The gateway's phases (histogram label: the name after ``route.``).
 index.snapshot, index.upload, index.dispatch, index.wait, index.ivf
     The index layer's steps, inside ``route.score``.
+fit.refine, fit.gate, fit.grow
+    The offline fit's phases in `build_router` (histogram label: the name
+    after ``fit.``), never inside a route batch.
 
 Device ops carry their step in the op metadata: ``score/`` and ``topk/``
 (`core.retrieval.topk_dense`, the Pallas top-K kernel), ``rerank/``

@@ -10,7 +10,9 @@ Each stamps `clock.perf()` at both ends. While a profiler trace is active
 profiler's host plane beside the device programs it enqueued; with no
 trace active none is created. At batch end the one list feeds the
 gateway's `route_phase_ms{phase}` and `index_step_ms{step}` histograms and,
-for a sampled batch, a `RouteTrace`.
+for a sampled batch, a `RouteTrace`. The offline fit of
+`launch.serve.build_router` opens one too (``fit.refine``, ``fit.gate``,
+``fit.grow``), which feeds `fit_phase_ms{phase}`.
 
 Histograms answer "what is p99"; traces answer "where did *this* slow batch
 spend it". The tracer samples ~1-in-N `route_batch` calls (seeded Bernoulli

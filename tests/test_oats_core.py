@@ -148,3 +148,86 @@ def test_s1_improves_over_static(small_bench):
     se = ev.rankings_for("se").metrics["ndcg@5"]
     s1 = ev.rankings_for("oats-s1").metrics["ndcg@5"]
     assert s1 > se + 0.02, (se, s1)
+
+
+# --------------------------------------------- Alg. 1 against a plain reference
+
+REF_ATOL = 1e-5  # float32 rounding of 4 passes on unit rows is ~1e-6; bf16 ~1e-3
+
+
+def _alg1_reference(te, qe, rel, pools, alpha, beta, iterations, momentum, k):
+    """Alg. 1's passes in float64 NumPy: the table after each pass."""
+    unit = lambda x: x / np.maximum(np.linalg.norm(x, axis=1, keepdims=True), 1e-9)  # noqa: E731
+    e, q, passes = te.astype(np.float64), qe.astype(np.float64), []
+    for n in range(iterations):
+        sims = q @ e.T if pools is None else np.where(pools > 0, q @ e.T, -1e30)
+        neg = np.zeros_like(rel, np.float64)
+        neg[np.arange(len(q))[:, None], np.argsort(-sims, axis=1, kind="stable")[:, :k]] = 1.0
+        neg *= 1.0 - rel
+        pos_n, neg_n = rel.sum(0), neg.sum(0)
+        pos_c = rel.T @ q / np.maximum(pos_n, 1.0)[:, None]
+        neg_c = neg.T @ q / np.maximum(neg_n, 1.0)[:, None]
+        e_hat = unit((1 - alpha) * e + alpha * pos_c - beta * (neg_n > 0)[:, None] * neg_c)
+        e_hat = np.where((pos_n > 0)[:, None], e_hat, e)
+        e = unit(momentum * e + (1 - momentum) * e_hat) if n > 0 else e_hat
+        passes.append(e)
+    return passes
+
+
+def _reference_recall(qe, table, rel, pools, k):
+    sims = qe.astype(np.float64) @ table.T
+    sims = sims if pools is None else np.where(pools > 0, sims, -1e30)
+    top = np.argsort(-sims, axis=1, kind="stable")[:, :k]
+    hits, n_rel = np.take_along_axis(rel, top, axis=1).sum(1), rel.sum(1)
+    return float((hits / np.maximum(n_rel, 1))[n_rel > 0].mean())
+
+
+_REF_CASES = [
+    (n, mu, masked, keep, False)
+    for n in (1, 2, 3, 4) for mu in (0.0, 0.5) for masked in (False, True)
+    for keep in (True, False)
+] + [(3, 0.5, False, True, True)]  # a bf16 fit: must come out off the reference
+
+
+@pytest.mark.parametrize("iterations,momentum,masked,keep_history,bf16", _REF_CASES)
+def test_refine_matches_plain_reference_pass_by_pass(
+    iterations, momentum, masked, keep_history, bf16
+):
+    qe, te, rel = _random_world(iterations + 10 * masked, q=80, t=24)
+    pools = None
+    if masked:  # each query's pool: its relevant tool and about 40% of the others
+        rng = np.random.default_rng(iterations)
+        pools = (rng.random(rel.shape) < 0.4).astype(np.float32)
+        pools[rel > 0] = 1.0
+    fit, val = slice(0, 64), slice(64, 80)
+    dtype = jnp.bfloat16 if bf16 else jnp.float32
+    args = (jnp.asarray(te, dtype), jnp.asarray(qe[fit], dtype), jnp.asarray(rel[fit]),
+            None if pools is None else jnp.asarray(pools[fit]))
+    kw = dict(alpha=0.3, beta=0.1, iterations=iterations, momentum=momentum, k=5)
+    ref = _alg1_reference(te, qe[fit], rel[fit], None if pools is None else pools[fit], **kw)
+
+    hist = np.asarray(refine_embeddings(*args, keep_history=True, **kw), np.float64)
+    final = np.asarray(refine_embeddings(*args, keep_history=False, **kw), np.float64)
+    assert hist.shape == (iterations + 1, *te.shape)
+    gaps = [np.abs(hist[p + 1] - ref[p]).max() for p in range(iterations)]
+    if bf16:
+        assert max(gaps) > REF_ATOL, gaps  # the tolerance tells a bf16 fit apart
+        return
+    np.testing.assert_array_equal(hist[0], te)
+    assert max(gaps) < REF_ATOL, gaps
+    np.testing.assert_allclose(final, hist[-1], atol=1e-6, rtol=0)
+
+    res = refine_with_gate(
+        *args[:3], jnp.asarray(qe[val]), jnp.asarray(rel[val]),
+        RefineConfig(keep_history=keep_history, **kw), args[3],
+        None if pools is None else jnp.asarray(pools[val]),
+    )
+    vpools = None if pools is None else pools[val]
+    before = _reference_recall(qe[val], te.astype(np.float64), rel[val], vpools, 5)
+    after = _reference_recall(qe[val], ref[-1], rel[val], vpools, 5)
+    assert bool(res.accepted) == (after >= before)
+    np.testing.assert_allclose(float(res.recall_before), before, atol=1e-6)
+    np.testing.assert_allclose(float(res.recall_after), after, atol=1e-6)
+    deployed = ref[-1] if after >= before else te
+    np.testing.assert_allclose(np.asarray(res.embeddings), deployed, atol=REF_ATOL, rtol=0)
+    assert (res.history is None) == (not keep_history)

@@ -13,7 +13,10 @@
 * the benchmark's trace reduction with program spans nested inside its
   own: device sums unchanged, idle gaps named after the innermost span;
 * the device ops of the score and re-rank programs carry their step in
-  the op metadata.
+  the op metadata;
+* the offline fit of `build_router`: one sample of each
+  `fit_phase_ms{phase=refine|gate|grow}`, one gate decision, the rows the
+  fit moved, nothing for the static stage, nothing on the route path.
 """
 import glob
 import sys
@@ -365,3 +368,64 @@ def test_device_ops_carry_their_step_in_op_metadata(program, scopes):
     hlo = _lowered(program).as_text(debug_info=True)
     for scope in scopes:
         assert scope in hlo
+
+
+# ------------------------------------------------------------ the offline fit
+
+FIT_PHASES = ("refine", "gate", "grow")
+
+
+def _fit_counts():
+    from repro.obs import get_registry
+
+    reg = get_registry()
+    return (
+        {p: reg.histogram("fit_phase_ms", phase=p).count() for p in FIT_PHASES},
+        {d: reg.counter("refine_gate_total", decision=d).value() for d in ("accepted", "rejected")},
+    )
+
+
+def _route_span_names(bench, stage, **kw):
+    """The span names of one sampled `route_batch` of a freshly built router."""
+    from repro.launch.serve import build_router
+
+    tracer = RouteTracer(sample_every=1, seed=0)
+    router, pipe = build_router(bench, stage, tracer=tracer, **kw)
+    try:
+        router.route_batch(list(bench.query_tokens[:3]))
+    finally:
+        router.close()
+    (trace,) = tracer.traces()
+    return [name for name, _ in trace.spans], pipe
+
+
+def test_one_fit_records_each_phase_once_and_its_gate(small_bench_sparse):
+    small_bench = small_bench_sparse  # many tools with no positive in the fit split
+    from repro.obs import get_registry
+
+    phases0, gate0 = _fit_counts()
+    names, pipe = _route_span_names(small_bench, "oats-s1", num_tools=2 * small_bench.n_tools)
+    phases1, gate1 = _fit_counts()
+    assert {p: phases1[p] - phases0[p] for p in FIT_PHASES} == dict.fromkeys(FIT_PHASES, 1)
+    accepted = bool(pipe.refine_result.accepted)
+    assert {d: gate1[d] - gate0[d] for d in gate1} == {
+        "accepted": float(accepted), "rejected": float(not accepted)}
+    # the fit split as the pipeline draws it; a tool's positives are its labels there
+    train = small_bench.train_idx
+    perm = np.random.default_rng(pipe.config.seed).permutation(len(train))
+    n_val = max(int(round(pipe.config.gate_val_frac * len(train))), 1)
+    fit = train[np.sort(perm[n_val:])]
+    with_positive = int((small_bench.relevance_matrix()[fit].sum(0) > 0).sum())
+    assert 0 < with_positive < small_bench.n_tools
+    assert get_registry().gauge("refine_rows_moved").value() == (with_positive if accepted else 0)
+    assert current_spans().enabled is False  # the fit's recorder is unbound again
+    # nothing of the fit on the route path: its spans are the static stage's
+    assert names == _route_span_names(small_bench, "se")[0]
+    assert "score" in names and not [n for n in names if n.startswith("fit")]
+
+
+def test_the_static_stage_records_no_fit_phase(small_bench):
+    before = _fit_counts()
+    _, pipe = _route_span_names(small_bench, "se")
+    assert pipe.refine_result is None
+    assert _fit_counts() == before

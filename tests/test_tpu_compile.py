@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.refine import RefineConfig, refine_with_gate
 from repro.core.retrieval import topk_dense
 from repro.index.pallas_backend import topk_sim_packed
 from repro.kernels.topk_sim.kernel import topk_sim_pallas
@@ -109,6 +110,24 @@ def test_packed_topk_programs_compile_for_v5e(one_chip, program, q, t, k):
         # packing in the same program did not push the kernel out
         assert "tpu_custom_call" in compiled.as_text()
     assert _fits(compiled)
+
+
+def test_refine_with_gate_compiles_for_v5e(one_chip):
+    """The OATS-S1 fit at the ToolBench cell's shapes: 2,413 tools, 357 fit and
+    63 gate queries with their candidate pools, three unrolled passes."""
+    t, q_fit, q_val = 2413, 357, 63
+
+    def fit(tools, qf, rf, qv, rv, mf, mv):
+        res = refine_with_gate(tools, qf, rf, qv, rv, RefineConfig(iterations=3), mf, mv)
+        return res.embeddings, res.accepted, res.history
+
+    f32 = lambda *shape: _struct(shape, jnp.float32, one_chip)  # noqa: E731
+    lowered = jax.jit(fit).lower(
+        f32(t, D), f32(q_fit, D), f32(q_fit, t), f32(q_val, D), f32(q_val, t),
+        f32(q_fit, t), f32(q_val, t),
+    )
+    assert lowered.out_info[2].shape == (4, t, D)  # the original table and 3 passes
+    assert _fits(lowered.compile())
 
 
 def test_full_width_qwen_decode_step_compiles_for_v5e(one_chip):
