@@ -5,9 +5,9 @@ encoder is deliberately *frozen* — OATS-S1 changes only the stored tool
 vectors, never the encoder (paper §4.1), and OATS-S3 composes a trainable
 adapter head on top of this encoder's output (paper §4.3).
 
-Both a ragged (list-of-token-arrays) numpy path — used by the offline
-benchmark/evaluation code — and a padded jnp path (used inside jitted serving
-and training code) are provided and agree exactly.
+Both a ragged (list-of-token-arrays) numpy path — the gateway's embed phase
+and the offline benchmark/evaluation code — and a padded jnp path (jittable,
+for training code) are provided; they agree to float32 rounding.
 """
 from __future__ import annotations
 
@@ -21,6 +21,9 @@ from repro.embedding.vocab import Vocab
 
 __all__ = ["BagEncoder"]
 
+# sorted rows per accumulation block of `BagEncoder.encode`
+_BLOCK = 256
+
 
 class BagEncoder:
     def __init__(self, vocab: Vocab):
@@ -32,15 +35,56 @@ class BagEncoder:
     def dim(self) -> int:
         return self.word_vecs.shape[1]
 
-    # ---- ragged numpy path (offline) ------------------------------------
+    # ---- ragged numpy path (offline and serving) ------------------------
     def encode(self, token_lists: Sequence[np.ndarray]) -> np.ndarray:
-        out = np.zeros((len(token_lists), self.dim), dtype=np.float32)
-        for i, toks in enumerate(token_lists):
-            if len(toks) == 0:
-                continue
-            v = self.word_vecs[np.asarray(toks)].mean(axis=0)
-            n = np.linalg.norm(v)
-            out[i] = v / max(n, 1e-9)
+        """[B, D] unit means of each list's word vectors; an empty list gives 0.
+
+        One batched pass whose rows are bit-identical to encoding each list
+        alone as ``v = word_vecs[toks].mean(0); v / max(norm(v), 1e-9)``:
+
+        - sums: ``mean`` adds a list's rows in token order in float32. The
+          rows, sorted by descending length, are accumulated the same way,
+          position by position, each position adding to the rows still open
+          there; once one row is left it is finished with one sequential
+          ``add.reduce`` (what ``mean`` itself runs). Nothing is padded, and a
+          long list next to short ones costs its own tokens;
+        - the mean divides by the length in the sum's dtype (``mean`` divides
+          by an integer count; the quotient rounds to the same value);
+        - norms: ``linalg.norm`` of a vector is ``sqrt(dot(v, v))``; ``matmul``
+          of a ``[1, D]`` row by a ``[D, 1]`` column runs that same dot, once
+          per row, with no Python loop.
+
+        Sorted rows are taken in blocks of `_BLOCK`, so the accumulator stays
+        in cache and a large offline batch needs one block of scratch.
+        """
+        n = len(token_lists)
+        out = np.zeros((n, self.dim), dtype=np.float32)
+        lengths = np.fromiter(map(len, token_lists), np.intp, count=n)
+        order = np.argsort(-lengths, kind="stable")[: np.count_nonzero(lengths)]
+        if not len(order):
+            return out
+        ln_all = lengths[order]
+        flat = np.concatenate([token_lists[i] for i in order.tolist()], dtype=np.intp)
+        starts_all = np.cumsum(ln_all) - ln_all
+        wv = self.word_vecs
+        for b0 in range(0, len(order), _BLOCK):
+            ln = ln_all[b0 : b0 + _BLOCK]
+            st = starts_all[b0 : b0 + _BLOCK]
+            acc = wv[flat[st]]
+            # positions open on two or more rows: ln[1] is the second longest
+            shared = int(ln[1]) if len(ln) > 1 else 1
+            open_rows = np.searchsorted(-ln, -np.arange(1, shared), side="left")
+            for pos, n_open in enumerate(open_rows.tolist(), start=1):
+                acc[:n_open] += wv[flat[st[:n_open] + pos]]
+            if ln[0] > shared:
+                # the longest row alone: its partial sum, then its later rows
+                rest = wv[flat[st[0] + shared - 1 : st[0] + ln[0]]]
+                rest[0] = acc[0]
+                acc[0] = np.add.reduce(rest, axis=0)
+            acc /= ln[:, None].astype(acc.dtype)
+            norm = np.sqrt(np.matmul(acc[:, None, :], acc[:, :, None]).reshape(-1))
+            acc /= np.maximum(norm, np.float32(1e-9))[:, None]
+            out[order[b0 : b0 + _BLOCK]] = acc
         return out
 
     def encode_one(self, tokens: np.ndarray) -> np.ndarray:

@@ -74,6 +74,9 @@ Metric catalog (gateway + index layer)
 
 route_requests_total (counter)
     Queries routed, summed over batches.
+route_tokens_total (counter)
+    Query tokens embedded, summed over batches (the embed phase's work:
+    its cost per batch grows with the tokens it gathers).
 route_batches_total (counter)
     `route_batch` calls served.
 route_phase_ms{phase=embed|cache|pad|adapter|score|rerank|assemble} (histogram)

@@ -121,6 +121,7 @@ class _GatewayInstruments:
     def __init__(self, registry: MetricsRegistry):
         self.registry = registry
         self.requests = registry.counter("route_requests_total")
+        self.tokens = registry.counter("route_tokens_total")
         self.batches = registry.counter("route_batches_total")
         self.batch_ms = registry.histogram("route_batch_ms")
         self.batch_size = registry.histogram("route_batch_size")
@@ -589,6 +590,8 @@ class SemanticRouter:
             with spans.span("route.telemetry") as telemetry:
                 self._record_batch(
                     spans, tracing, n_q,
+                    # the tokens the embed phase gathered: its cost per batch
+                    n_tokens=sum(map(len, queries)),
                     # the bucket is what the jitted programs compiled for:
                     # the padded MISS block (an all-hit batch never reached
                     # them and reports bucket 0 under path "cache")
@@ -612,6 +615,7 @@ class SemanticRouter:
         spans: SpanRecorder,
         tracing: bool,
         n_q: int,
+        n_tokens: int,
         bucket: int,
         path: str,
         table_version: int,
@@ -641,6 +645,7 @@ class SemanticRouter:
             return
         exemplar = trace.trace_id if trace is not None else None
         obs.requests.inc(n_q)
+        obs.tokens.inc(n_tokens)
         obs.batches.inc()
         obs.batch_size.record(float(n_q))
         obs.batch_ms.record(total_ms, exemplar=exemplar)

@@ -11,8 +11,9 @@ from hypothesis import given, settings, strategies as st
 from repro import optim
 from repro.checkpoint.msgpack_ckpt import restore_checkpoint, save_checkpoint
 from repro.core.baselines import BM25
-from repro.data.benchmarks import SUBTASKS, make_metatool_like
+from repro.data.benchmarks import SUBTASKS, make_metatool_like, make_toolbench_like
 from repro.data.lm_data import LMDataConfig, synthetic_lm_batches
+from repro.embedding import bag_encoder
 from repro.embedding.bag_encoder import BagEncoder, pad_token_lists
 from repro.embedding.transformer import EncoderConfig, encode, encoder_param_count, init_encoder
 
@@ -51,6 +52,70 @@ def test_encoders_agree(small_bench):
     padded = np.asarray(enc.encode_padded(jnp.asarray(ids), jnp.asarray(mask)))
     np.testing.assert_allclose(ragged, padded, atol=1e-5)
     np.testing.assert_allclose(np.linalg.norm(ragged, axis=1), 1.0, atol=1e-5)
+
+
+def _encode_per_query(word_vecs, token_lists):
+    """The per-query loop `BagEncoder.encode` replaced: its bit-exact reference."""
+    out = np.zeros((len(token_lists), word_vecs.shape[1]), dtype=np.float32)
+    for i, toks in enumerate(token_lists):
+        if len(toks) == 0:
+            continue
+        v = word_vecs[np.asarray(toks)].mean(axis=0)
+        n = np.linalg.norm(v)
+        out[i] = v / max(n, 1e-9)
+    return out
+
+
+@pytest.fixture(scope="module")
+def toolbench_bench():
+    # the data both benchmark configurations serve: 2,413 tools, 600 queries
+    return make_toolbench_like(seed=0)
+
+
+def _ragged_lists(rng, n_words):
+    """Short rows around an empty list, a length-1 list and a 5,000-token one."""
+    rows = [rng.integers(0, n_words, rng.integers(7, 18)) for _ in range(60)]
+    rows += [np.zeros(0, np.int64), np.array([3]), rng.integers(0, n_words, 5000)]
+    return [rows[i] for i in rng.permutation(len(rows))]
+
+
+_ENCODE_CASES = {
+    "small-descriptions": lambda b, s, rng: s.desc_tokens,
+    "small-queries": lambda b, s, rng: s.query_tokens,
+    "toolbench-descriptions": lambda b, s, rng: b.desc_tokens,
+    "toolbench-queries": lambda b, s, rng: b.query_tokens,
+    "ragged-empty-one-outlier": lambda b, s, rng: _ragged_lists(rng, len(b.vocab.word_vecs)),
+    "only-empty": lambda b, s, rng: [np.zeros(0, np.int64), []],
+    "batch-of-one": lambda b, s, rng: b.query_tokens[:1],
+    "two-longest-tie": lambda b, s, rng: [np.arange(9), np.arange(20, 29), np.arange(4)],
+    "larger-than-a-block": lambda b, s, rng: [
+        rng.integers(0, len(b.vocab.word_vecs), rng.integers(0, 40))
+        for _ in range(3 * bag_encoder._BLOCK + 7)
+    ],
+    "int32-ids": lambda b, s, rng: [t.astype(np.int32) for t in b.query_tokens],
+    "int64-ids": lambda b, s, rng: [t.astype(np.int64) for t in b.query_tokens],
+    "mixed-dtypes-and-lists": lambda b, s, rng: [
+        np.array([7, 7, 7], np.int16), [1, 2, 3, 4], np.array([9], np.uint8),
+        b.query_tokens[0].astype(np.int32), [],
+    ],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ENCODE_CASES))
+def test_batched_encode_is_bit_identical_to_the_per_query_loop(
+    case, toolbench_bench, small_bench
+):
+    bench = small_bench if case.startswith("small") else toolbench_bench
+    token_lists = _ENCODE_CASES[case](
+        toolbench_bench, small_bench, np.random.default_rng(7)
+    )
+    enc = BagEncoder(bench.vocab)
+    ref = _encode_per_query(bench.vocab.word_vecs, token_lists)
+    got = enc.encode(token_lists)
+    assert got.dtype == np.float32 and got.shape == ref.shape
+    assert np.array_equal(got, ref)
+    for i, toks in enumerate(token_lists[:4]):
+        assert np.array_equal(enc.encode_one(toks), ref[i])
 
 
 def test_transformer_encoder_is_minilm_shaped():

@@ -226,6 +226,18 @@ def test_counters_exact_under_concurrent_route_batch():
     assert reg.histogram("route_obs_ms").count() == total
 
 
+def test_route_tokens_total_counts_the_batch_tokens():
+    reg = MetricsRegistry()
+    router, _ = _make_router(metrics=reg)
+    queries = [np.arange(7), np.arange(3, dtype=np.int32), np.arange(12), [5]]
+    router.route_batch(queries)
+    assert reg.counter("route_tokens_total").value() == 7 + 3 + 12 + 1
+    assert reg.counter("route_requests_total").value() == len(queries)
+    router.route_batch(queries[:2])
+    assert reg.counter("route_tokens_total").value() == 23 + 10
+    assert reg.counter("route_requests_total").value() == len(queries) + 2
+
+
 # ------------------------------------------------------------------ EventBus
 
 
