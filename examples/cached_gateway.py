@@ -21,8 +21,9 @@ is visible:
      staleness gate in `repro.traffic.drive` confirms nothing was served
      from the dead snapshot.
 
-The full measurement (25k tools, three Zipf exponents, churn leg, CI
-gates) lives in `benchmarks/cache_bench.py`; this is the 30-second tour.
+`tests/test_cache.py` holds the cache's correctness (zero stale serves
+under churn; hit rate and agreement on the seeded Zipf-1.1 stream); this
+is the 30-second tour.
 """
 import argparse
 

@@ -3,9 +3,13 @@
 The router's latency story rests on invariants generic linters cannot see:
 compare-and-swap-only version swaps, atomic snapshot reads, one portable
 mesh layer, one bucketing function, no device work under hot-path locks.
-This package enforces them in CI.
+This package enforces them.
 
-Three legs, all run by ``scripts/ci_check.sh``:
+Three legs, run by ``scripts/ci_check.sh`` ahead of the tests; the tests
+run each one too (`tests/test_analysis.py`:
+``test_repo_is_clean_under_checked_in_baseline``,
+``test_route_batch_stays_inside_bucket_set``,
+``test_live_planes_have_no_cycles_or_dispatch_under_lock``):
 
 * ``python -m repro.analysis [paths]`` — AST lint (this file's catalog below);
 * ``python -m repro.analysis.retrace`` — runtime jit-retrace detector that

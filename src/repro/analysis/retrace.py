@@ -14,8 +14,9 @@ Two consumers:
   (dense backend, adapter stage active), sweeps mixed batch sizes through
   `route_batch`, and fails if any hot-path entry point traced more than
   once per (power-of-two bucket x live table/stage generation);
-* `benchmarks/router_bench.py` — wraps its sweep in a monitor so the
-  perf numbers and the retrace contract are checked by the same run.
+* `tests/test_analysis.py` — runs the same scenario
+  (`test_route_batch_stays_inside_bucket_set`) and the monitor's own
+  contract tests.
 
 `_cache_size` is a private-but-stable jax API (present throughout the
 0.4.x line this repo pins). When a jitted callable does not expose it the

@@ -13,19 +13,19 @@ Choosing a config (mirrors the backend-selection guides in `repro.index` /
 ``threshold`` — the correctness knob. A hit is served only when
     cosine(stored query, new query) >= threshold; everything else about the
     cache (LSH tables, LRU) only affects *where* it looks, never *whether*
-    a far query can be served. 0.95 (default) keeps routing agreement with
-    the uncached path >= 0.98 on paraphrase-jittered streams
-    (BENCH_cache.json); raise toward 0.99 for registries with many
+    a far query can be served. 0.95 (default) keeps top-1 agreement with
+    the uncached path >= 0.98 on the seeded Zipf-1.1 paraphrase-jittered
+    stream (`tests/test_cache.py`); raise toward 0.99 for registries with many
     near-synonym tools, lower toward 0.9 only when the tool corpus is
     coarse and hit-rate matters more than tail agreement. ``threshold=2.0``
-    is the supported "never hit" setting used to measure pure cache
-    overhead (see `benchmarks/obs_bench.py`).
+    is the supported "never hit" setting: every batch pays the probe and
+    insert, and nothing is served from the cache.
 
 ``min_gap`` — conservative serving guard: a hit is additionally required
     to have had a stored top-1 minus top-2 score gap >= min_gap, since a
     near-duplicate can only flip the top-1 decision when the stored gap is
     small relative to the query perturbation. Default 0.0 (off) — on the
-    benched Zipf streams it cost hit-rate without buying agreement — but
+    seeded Zipf streams it cost hit-rate without buying agreement — but
     raise it (~0.05) for registries where serving a flipped top-1 is much
     worse than a cache miss.
 
@@ -59,9 +59,8 @@ Pass the cache to `SemanticRouter(..., cache=...)` — the gateway probes it
 after embedding (keys are embedding-space), scores only the miss subset,
 inserts fresh results, and re-checks every served entry's stamps against
 the live snapshot (`route_cache_stale_served_total` must stay 0; the
-``cache_staleness`` SLO and `benchmarks/cache_bench.py`'s churn gate hold
-it there). Traffic realism lives in `repro.traffic`; the recorded
-hit-rate × qps × p99 curves in BENCH_cache.json.
+``cache_staleness`` SLO alerts on it, and `tests/test_cache.py` holds it
+there under threaded churn). Traffic realism lives in `repro.traffic`.
 """
 from repro.cache.route_cache import CacheConfig, CachedRoute, SemanticRouteCache
 

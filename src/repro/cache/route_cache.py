@@ -68,8 +68,8 @@ class CacheConfig:
     seed: int = 0  # hyperplane RNG seed (deterministic keys per seed)
 
     def __post_init__(self):
-        # threshold > 1 is the supported "never hit" setting for measuring
-        # pure cache overhead (benchmarks/obs_bench.py)
+        # threshold > 1 is the supported "never hit" setting: probe and
+        # insert run, nothing is served
         assert 0.0 < self.threshold, self.threshold
         assert 1 <= self.n_bits <= 48, self.n_bits  # packed into one int64
         assert 1 <= self.n_tables <= 64, self.n_tables

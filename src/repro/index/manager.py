@@ -177,7 +177,7 @@ class ToolIndexManager:
         return backend is not None and backend.table_version == self.db.table_version
 
     def wait_ready(self, timeout_s: float = 60.0, poll_s: float = 0.01) -> bool:
-        """Block until the index is fresh (benchmarks/tests); True on success.
+        """Block until the index is fresh (launcher/tests); True on success.
 
         Returns False immediately (not after the full timeout) when the
         build for the live version has already failed and nothing is

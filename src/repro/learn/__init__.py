@@ -48,10 +48,6 @@ Both gates are empirical on top of the density policy: a stage activates
 only if it beats the live configuration's held-out NDCG@5 on the window's
 positive-bearing queries, and stays only while live labelled traffic
 agrees (`StageGuard`).
-
-`benchmarks/learn_bench.py` records the density sweep (refine-only vs
-+adapter vs +reranker NDCG@5) and the all-stages-active `route_batch`
-p99/query against the 10 ms budget in BENCH_learn.json.
 """
 from repro.learn.controller import (
     LearnConfig,

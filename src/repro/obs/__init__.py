@@ -2,9 +2,9 @@
 
 The paper's pitch is a latency budget ("all mechanisms run within
 single-digit millisecond CPU budgets", §5.5); this package makes that
-budget *observable at serve time* instead of only in offline benches, at a
-cost `benchmarks/obs_bench.py` bounds in CI (<5 % of bare `route_batch`
-qps). Four surfaces:
+budget *observable at serve time* instead of only in offline benches; the
+gateway times its own telemetry per batch (`route_obs_ms`, span
+``route.telemetry``, outside `route_batch_ms`). Four surfaces:
 
 * `repro.obs.metrics` — process-wide `MetricsRegistry` of counters, gauges,
   and preallocated log-spaced-bucket histograms (O(1) record, bounded
@@ -137,7 +137,8 @@ route_cache_invalidated_total (counter)
 route_cache_stale_served_total (counter)
     Gateway-tripwire demotions: a cache hit whose stamps no longer match
     the live `(table_version, stage_version)` at serve time. MUST stay 0
-    (the ``cache_staleness`` SLO and cache_bench's churn gate enforce it).
+    (the ``cache_staleness`` SLO alerts on it; `tests/test_cache.py` holds
+    it at 0 under threaded control-plane churn).
 index_served_total{path=index|exact} (counter)
     Batches served by the built backend vs the exact dense fallback
     (fallback-serving windows during rebuilds).

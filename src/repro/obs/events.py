@@ -4,8 +4,9 @@ Before this module, lifecycle transitions were scattered prints and
 write-only attributes: a swap was visible only in a `ControllerReport`
 someone kept a reference to, a guard rollback only in `guard.rollbacks`, an
 index rebuild not at all. The bus gives every plane one `publish()` call
-and every consumer (health endpoint, examples, the lifecycle smoke in
-`benchmarks/obs_bench.py`) one ordered stream with version stamps.
+and every consumer (health endpoint, flight recorder, examples) one
+ordered stream with version stamps; `tests/test_obs.py` checks the stamps
+of each lifecycle event while another thread serves.
 
 Design constraints, in the same spirit as `OutcomeStore`:
 

@@ -7,8 +7,8 @@ never appends to a list and memory is bounded no matter how long the
 process serves. `record`/`inc` are O(1): one `bisect` over ~80 edges
 plus a few scalar updates under a per-instrument lock (uncontended CPython
 locks are ~100 ns; `route_batch` records ~10 values per *batch*, so the
-instrumentation budget is microseconds against a millisecond batch —
-`benchmarks/obs_bench.py` enforces the <5 % overhead bound in CI).
+instrumentation budget is microseconds against a millisecond batch — the
+gateway's `route_obs_ms` histogram records what it actually costs).
 
 Instruments are get-or-create by (name, labels) — calling
 ``registry.histogram("route_phase_ms", phase="embed")`` twice returns the
@@ -120,7 +120,7 @@ class LogHistogram:
         assert bool(np.all(np.diff(self.edges) > 0)), "edges must be ascending"
         # scalar bucket lookup uses bisect over this plain list: ~20x less
         # per-call overhead than numpy's scalar searchsorted (~2 µs), which
-        # obs_bench's profile showed dominating the per-record cost at ~7
+        # a host profile showed dominating the per-record cost at ~7
         # records per batch
         self._edges_list: List[float] = self.edges.tolist()
         self._counts = np.zeros(len(self.edges) + 1, dtype=np.int64)

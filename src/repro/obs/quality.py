@@ -92,8 +92,8 @@ class QualityConfig:
     # fold only every Nth batch into the drift EWMA (1 = every batch). The
     # EWMA's horizon is tens of folds, so a small stride changes detection
     # latency by a few batches while cutting the per-route_batch cost by
-    # ~1/stride — serve.py and obs_bench run stride 4 as the production
-    # shape; the default keeps every-batch semantics for tests and guards
+    # ~1/stride — serve.py runs stride 4 as the production shape; the
+    # default keeps every-batch semantics for tests and guards
     drift_every: int = 1
 
 
@@ -221,8 +221,8 @@ class QualityMonitor:
             else:
                 # in-place fold (and scratch reuse below): this runs on every
                 # route_batch, and the allocation-free form halves the
-                # cache-cold per-batch cost obs_bench's profile attributed
-                # here (temporaries dominate, not flops)
+                # cache-cold per-batch cost a host profile attributed here
+                # (temporaries dominate, not flops)
                 self._ew_mean *= np.float32(1.0) - a
                 batch_mean *= a
                 self._ew_mean += batch_mean

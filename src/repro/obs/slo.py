@@ -58,7 +58,7 @@ class BurnWindow:
 
 # Google SRE's two fastest pairs for a 30-day period: page on a budget
 # burning in ~2 days (14.4x over 1h, confirmed over 5m) or in ~5 days
-# (6x over 6h, confirmed over 30m). Smoke benches substitute second-scale
+# (6x over 6h, confirmed over 30m). Tests substitute second-scale
 # pairs — the math is window-agnostic.
 DEFAULT_WINDOWS: Tuple[BurnWindow, ...] = (
     BurnWindow(long_s=3600.0, short_s=300.0, factor=14.4),

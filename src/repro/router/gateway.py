@@ -251,11 +251,10 @@ class SemanticRouter:
         self.index = index if index is not None else ToolIndexManager(
             db, backend=backend, backend_opts=backend_opts, bus=bus
         )
-        # telemetry: metrics default ON against the process registry
-        # (`benchmarks/obs_bench.py` bounds the cost in CI at <5 % of bare
-        # qps); `metrics=False` is the truly bare hot path the bench
-        # compares against. Instruments are resolved once here so
-        # `route_batch` never takes the registry lock.
+        # telemetry: metrics default ON against the process registry (the
+        # per-batch cost lands in `route_obs_ms`); `metrics=False` records
+        # nothing. Instruments are resolved once here so `route_batch`
+        # never takes the registry lock.
         if metrics is False:
             self._obs: Optional[_GatewayInstruments] = None
         else:
@@ -448,7 +447,7 @@ class SemanticRouter:
                 # entry whose versions differ from the live pair is demoted
                 # to a miss (never served) and counted —
                 # route_cache_stale_served_total must stay 0 (cache_staleness
-                # SLO; benchmarks/cache_bench.py gates it in CI)
+                # SLO; tests/test_cache.py holds it under threaded churn)
                 stale = 0
                 for j, e in enumerate(cached):
                     if e is not None and (

@@ -7,7 +7,7 @@ exactly the paper's protocol. The embedding forward uses the MiniLM-shaped
 term matches the production router's, independent of weight values.
 
 The percentile math itself lives in `repro.obs.summary` (one implementation
-shared by this harness, the benchmarks, and the tracer report);
+shared by this harness and the tracer report);
 `LatencyStats`/`percentile_stats` are re-exported here for compatibility.
 """
 from __future__ import annotations

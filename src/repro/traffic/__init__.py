@@ -5,8 +5,8 @@ benches replay: request popularity is Zipfian (a small hot set dominates),
 repeats are *near*-duplicates (paraphrases, not byte-equal), arrival is
 bursty, and the hot set drifts. This package synthesizes that shape,
 deterministically per seed, so two runs — e.g. a bare router and a cached
-one in `benchmarks/cache_bench.py` — can be driven with the IDENTICAL
-stream and compared query-for-query.
+one in `tests/test_cache.py` — can be driven with the IDENTICAL stream and
+compared query-for-query.
 
 `ZipfTrafficGenerator` (generator.py) samples ranks from a Zipf(s) law over
 a fixed pool of distinct intents, applies paraphrase jitter (token
@@ -21,7 +21,7 @@ served `(table_version, stage_version)` must lie inside the live version
 window read around the call (versions are monotone, so the window is
 exact even while control-plane swaps land concurrently mid-stream).
 `agreement` compares two replays of the same stream top-1-for-top-1 — the
-cached-vs-uncached routing-agreement number BENCH_cache.json records.
+cached-vs-uncached routing agreement `tests/test_cache.py` holds >= 0.98.
 """
 from repro.traffic.generator import TrafficConfig, ZipfTrafficGenerator
 from repro.traffic.harness import TrafficReport, agreement, drive

@@ -32,8 +32,8 @@ class TrafficConfig:
 class ZipfTrafficGenerator:
     """Deterministic per (config, call sequence): two generators built from
     the same config emit the IDENTICAL stream, which is what lets
-    `benchmarks/cache_bench.py` replay one stream through a bare router and
-    a cached one and compare agreement query-for-query."""
+    `tests/test_cache.py` replay one stream through a bare router and a
+    cached one and compare agreement query-for-query."""
 
     def __init__(
         self,

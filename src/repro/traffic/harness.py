@@ -8,8 +8,8 @@ bumps — see `ToolsDatabase` / `SemanticRouter.set_stages`), so
 [versions-at-entry, versions-at-exit] is an exact bound on what any
 correct path — cached or not — may serve, even while control-plane churn
 lands concurrently mid-stream. A violation means a cache served a decision
-from a dead snapshot; `benchmarks/cache_bench.py` fails CI on the first
-one.
+from a dead snapshot; `examples/cached_gateway.py` exits nonzero on the
+first one.
 """
 from __future__ import annotations
 
@@ -48,7 +48,7 @@ def drive(
     Batches are materialized by the caller (`list(gen.stream(n))`) so the
     generator's cost never pollutes the timing, and so the same list can be
     replayed against a second router. `on_batch(i)` runs between batches —
-    the hook cache_bench uses to fire control-plane swaps mid-stream.
+    the hook for firing control-plane swaps mid-stream.
     `record=True` retains every RouteResult for `agreement` comparison.
     """
     lat_ms: List[float] = []

@@ -3,7 +3,8 @@
 * black-box dumps — an injected ``slo_burn`` and an injected controller
   crash each produce exactly ONE crash-consistent dump (debounce dedupes
   the storm), with the triggering event, >=1 linked RouteTrace, and
-  (table_version, stage_version) stamps matching the serving router;
+  (table_version, stage_version) stamps matching the serving router; a
+  routed latency burn's dump holds the burn's p99 exemplar trace;
 * crash consistency — abandoned ``.tmp-`` staging dirs are never listed
   and get swept; retention keeps only the newest ``max_dumps``;
 * ``repro-obs replay`` renders a dump offline (trigger + timeline + trace
@@ -142,6 +143,51 @@ def test_slo_burn_triggers_exactly_one_debounced_dump(tmp_path):
         assert reg.counter("flightrec_dumps_total").value() == 1.0
         assert reg.counter("flightrec_suppressed_total").value() == 1.0
     finally:
+        router.close()
+
+
+def test_routed_latency_burn_dump_holds_its_p99_exemplar_trace(tmp_path):
+    """A real breach, not a published one: every routed batch pays more
+    than the 10 ms threshold in embed, the engine's ``slo_burn`` names a
+    p99 exemplar from the router's own histogram, and the one dump it
+    triggers holds that trace, so a postmortem needs no live process."""
+    bus = EventBus()
+    reg = MetricsRegistry()
+    tracer = RouteTracer(sample_every=1)
+
+    def slow_embed(token_lists):
+        time.sleep(0.015)  # > the SLO's 10 ms threshold on every batch
+        return _embed_batch(token_lists)
+
+    router, db = _make_router(metrics=reg, tracer=tracer, bus=bus,
+                              embed_batch_fn=slow_embed)
+    ring = TimeSeriesRing(reg, bus=bus)
+    slo = SLO(name="route_latency_budget", kind="latency",
+              hist_key="route_batch_ms", threshold_ms=10.0, objective=0.90,
+              windows=(BurnWindow(long_s=100.0, short_s=100.0, factor=1.0),))
+    engine = SLOEngine(ring, slos=(slo,), bus=bus, registry=reg)
+    rec = FlightRecorder(str(tmp_path / "dumps"), bus=bus, registry=reg,
+                         tracer=tracer, ring=ring, slo=engine,
+                         routers=[router], debounce_s=60.0)
+    try:
+        ring.tick(now=0.0)
+        for seed in range(4):
+            _route_some(router, seed=seed)
+        ring.tick(now=50.0)
+        snap = engine.evaluate(now=50.0)
+        assert snap["burning"] == ["route_latency_budget"]
+        burn = bus.last("slo_burn")
+        assert burn is not None and burn.details["p99_ms"] > 10.0
+        exemplar = burn.details["p99_exemplar"]
+        assert tracer.get(exemplar) is not None
+        [dump] = rec.list()
+        assert dump.manifest["reason"] == "slo_burn"
+        traces = load_dump(dump.path)["traces"]
+        assert exemplar in [t["trace_id"] for t in traces]
+        assert all(t["table_version"] == db.table_version for t in traces)
+        assert "reason: slo_burn" in render_replay(dump.path)
+    finally:
+        rec.stop()
         router.close()
 
 

@@ -14,6 +14,8 @@
   loop_recovered published on transitions only), `outcomes_dropped`
   surfaces through counter + bus + degraded health;
 * ObsServer HTTP endpoints (/metrics, /health 200 vs 503, /events?since=);
+* lifecycle events (swap, rebuilds, rollback + cooldown, stage swap,
+  demotion) stamped with the right versions while a thread serves;
 * the `repro.router.latency` re-export compatibility surface.
 """
 import json
@@ -444,6 +446,101 @@ def test_obs_server_health_returns_503_on_loop_error():
         assert "dead loop" in snap["control"][0]["last_loop_error"]
     finally:
         server.stop()
+
+
+# -------------------------------------------- lifecycle events under serving
+
+
+def test_lifecycle_events_reach_the_bus_stamped_while_serving():
+    """A table swap, a guard rollback with the controller's cooldown, and a
+    stage demotion land while another thread serves `route_batch`: each
+    lifecycle event reaches the bus with the versions it acted on, and
+    the serving thread raises nothing."""
+    from repro.control import GuardConfig, TableGuard
+    from repro.learn import StageGuard, StageGuardConfig
+    from repro.router.stages import StageSet
+
+    bus = EventBus()
+    router, db = _make_router(
+        metrics=MetricsRegistry(), tracer=RouteTracer(sample_every=1), bus=bus
+    )
+    bus.watch_db(db)
+    store = OutcomeStore(n_tools=len(db))
+    guard = TableGuard(db, GuardConfig(min_samples=32), bus=bus)
+    controller = RefinementController(
+        db, store, _embed_batch, routers=[router], guard=guard, bus=bus,
+        # swaps are driven by hand; the refinement trigger stays cold
+        config=ControllerConfig(min_events=10**9, max_interval_s=10**9),
+    )
+    stage_guard = StageGuard(router, StageGuardConfig(min_samples=32), bus=bus)
+    blocks = [[np.arange(j, j + 4) for j in range(lo, lo + 8)]
+              for lo in range(0, 32, 8)]
+    stop = threading.Event()
+    served = [0]
+    errors = []
+
+    def serve():
+        try:
+            while not stop.is_set():
+                router.route_batch(blocks[served[0] % len(blocks)])
+                served[0] += 1
+        except Exception as exc:  # noqa: BLE001 — asserted below
+            errors.append(exc)
+
+    def labels(observe, version, good):
+        for _ in range(40):  # synthetic labels: deterministic verdicts
+            observe(version, [1, 2, 3], [1] if good else [9])
+
+    t = threading.Thread(target=serve, daemon=True)
+    t.start()
+    try:
+        assert _wait_for(lambda: served[0] >= 2, timeout_s=10.0)
+        # table: a healthy window on v0, an unannounced bad swap, then a
+        # regression the guard rolls back and the controller cools down on
+        v0 = db.table_version
+        labels(guard.observe, v0, good=True)
+        rng = np.random.default_rng(0)
+        bad = db.embeddings.copy()
+        rng.shuffle(bad, axis=0)
+        v_bad = db.swap_table(bad, expect_current=v0)
+        controller.step()  # freezes v0's window as the baseline
+        labels(guard.observe, v_bad, good=False)
+        report = controller.step()
+        assert report.guard is not None
+        assert report.guard.action == "rolled_back"
+        assert "cooldown" in report.reason
+        v_restored = db.table_version
+
+        # stages: an out-of-band StageSet swap, then a regression the
+        # stage guard demotes
+        sv0 = router.stage_version
+        labels(stage_guard.observe, sv0, good=True)
+        sv_bad = router.set_stages(StageSet())
+        assert stage_guard.check().action == "insufficient_data"
+        labels(stage_guard.observe, sv_bad, good=False)
+        assert stage_guard.check().action == "demoted"
+        sv_restored = router.stage_version
+        assert router.index.wait_ready(timeout_s=10.0)
+        served_at_end = served[0]
+        assert _wait_for(lambda: served[0] > served_at_end, timeout_s=10.0)
+    finally:
+        stop.set()
+        t.join(timeout=10.0)
+        router.close()
+    assert not t.is_alive()
+    assert errors == []
+
+    counts = bus.counts()
+    for kind in ("swap", "rebuild_start", "rebuild_finish", "rollback",
+                 "cooldown", "stage_swap", "demotion"):
+        assert counts.get(kind), (kind, counts)
+    assert v_bad in [e.details["version"] for e in bus.events(kind="swap")]
+    rb = bus.last("rollback").details
+    assert (rb["condemned_version"], rb["restored_version"]) == (
+        v_bad, v_restored)
+    dm = bus.last("demotion").details
+    assert (dm["condemned_version"], dm["restored_version"]) == (
+        sv_bad, sv_restored)
 
 
 # ------------------------------------------------------- latency re-exports

@@ -17,7 +17,8 @@
 * `RollingWindows` — bounded per-key windows, pruning;
 * `QualityMonitor` — rolling NDCG/Recall gauges, drift rising-edge +
   re-arm, the `watch_db` reference-follows-swaps contract and its detach
-  handle (EventBus.watch_db's detach too);
+  handle (EventBus.watch_db's detach too), and a routed bad swap raising
+  ``quality_drift`` before the labelled guard's rollback;
 * HTTP surface — /slo judging live and /traces?id= resolving exemplars;
 * `repro-obs` — --since filtering, --follow tailing, --watch panel
   rendering with an exemplar-to-trace link.
@@ -462,6 +463,85 @@ def test_watch_db_follows_swaps_and_detaches():
     db.swap_table(db.embeddings + 2.0, expect_current=v1)
     assert qm.summary()["ref_table_version"] == v1  # no longer following
     assert bus.last("swap").details["version"] == v1  # no new event
+
+
+def test_bad_swap_raises_drift_before_the_guard_rolls_back():
+    """The label-free drift signal leads the labelled guard: a bad table
+    swapped under routed traffic publishes ``quality_drift`` (stamped with
+    the bad version) before `TableGuard` has the labels to roll it back,
+    and the drift latch re-arms once the restored table serves again."""
+    from repro.control import GuardConfig, TableGuard
+    from repro.data.benchmarks import make_metatool_like
+    from repro.embedding.bag_encoder import BagEncoder
+    from repro.router.gateway import SemanticRouter
+
+    bench = make_metatool_like(seed=0, n_tools=64, n_queries=256)
+    enc = BagEncoder(bench.vocab)
+    db = ToolsDatabase(
+        [ToolRecord(i, f"tool_{i}", bench.desc_tokens[i],
+                    int(bench.tool_category[i]))
+         for i in range(bench.n_tools)],
+        enc.encode(bench.desc_tokens),
+    )
+    bus = EventBus()
+    bus.watch_db(db)
+    quality = QualityMonitor(registry=MetricsRegistry(), bus=bus)
+    quality.watch_db(db)
+    router = SemanticRouter(
+        db, embed_fn=enc.encode_one, embed_batch_fn=enc.encode, k=5,
+        metrics=MetricsRegistry(), bus=bus, quality=quality,
+    )
+    guard = TableGuard(db, GuardConfig(min_samples=32), bus=bus)
+    blocks = [
+        [bench.query_tokens[qi] for qi in bench.train_idx[lo : lo + 16]]
+        for lo in range(0, 64, 16)
+    ]
+    try:
+        # healthy window: past the detector's min_batches, no drift; the
+        # guard collects a labelled baseline on v0 (synthetic labels)
+        for i in range(quality.config.drift_min_batches + 2):
+            router.route_batch(blocks[i % len(blocks)])
+        assert not quality.drifting and bus.last("quality_drift") is None
+        v0 = db.table_version
+        for _ in range(40):
+            guard.observe(v0, [1, 2, 3], [1])
+
+        # bad swap: the row shuffle breaks per-tool geometry (what the
+        # labels catch); the mean shift moves the population stats (what
+        # the label-free detector catches)
+        rng = np.random.default_rng(0)
+        bad = db.embeddings.copy()
+        rng.shuffle(bad, axis=0)
+        bad += 3.0 * bad.std()
+        v_bad = db.swap_table(bad, expect_current=v0)
+        router.route_batch(blocks[0])
+        drift_ev = bus.last("quality_drift")
+        assert drift_ev is not None and quality.drifting
+        assert bus.last("rollback") is None
+
+        assert guard.check().action == "insufficient_data"  # baseline frozen
+        for _ in range(40):
+            guard.observe(v_bad, [1, 2, 3], [9])
+        report = guard.check()
+        assert report.action == "rolled_back"
+        v_restored = db.table_version
+        assert v_restored == report.restored_version != v_bad
+
+        rollback_ev = bus.last("rollback")
+        assert rollback_ev is not None
+        assert drift_ev.seq < rollback_ev.seq
+        d = drift_ev.details
+        assert d["table_version"] == v_bad and d["score"] > d["threshold"]
+        rd = rollback_ev.details
+        assert rd["condemned_version"] == v_bad
+        assert rd["restored_version"] == v_restored
+
+        # re-arm: the restored table's stats match the traffic again
+        router.route_batch(blocks[1])
+        assert not quality.drifting
+        assert bus.counts()["quality_drift"] == 1
+    finally:
+        router.close()
 
 
 # ------------------------------------------------------------- HTTP surface
