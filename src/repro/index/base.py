@@ -29,8 +29,12 @@ The device backends (dense, pallas, and the manager's exact fallback, a
 `DenseBackend`) share one host round trip, `round_trip`: their jitted
 program returns the top-K as one packed `[Q, 2k]` int32 block
 (`core.retrieval.pack_topk`), so each call makes one copy up (two with a
-mask) and one copy back. It times its steps as spans of the calling batch
-(`repro.obs.trace.current_spans`).
+mask) and one copy back. Where the table's device lists a `pinned_host`
+memory (see `pinned_host`), the NumPy query block goes straight to the
+jitted call, and the program writes its block to pinned host memory by its
+own copy, so the host reads host memory and starts no copy back; elsewhere
+the host copies the block back from device memory. It times its steps as
+spans of the calling batch (`repro.obs.trace.current_spans`).
 """
 from __future__ import annotations
 
@@ -39,11 +43,12 @@ from typing import Callable, Optional, Protocol, Tuple, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.retrieval import NEG_INF, unpack_topk
 from repro.obs.trace import current_spans
 
-__all__ = ["NEG_INF", "ScorerBackend", "round_trip"]
+__all__ = ["NEG_INF", "ScorerBackend", "pinned_host", "round_trip"]
 
 
 @runtime_checkable
@@ -65,41 +70,65 @@ class ScorerBackend(Protocol):
         ...
 
 
+def pinned_host(table: jax.Array) -> Optional[SingleDeviceSharding]:
+    """Pinned host memory of the device that holds `table`, as a sharding,
+    or None where that device lists no `pinned_host` memory."""
+    (device,) = table.devices()
+    if not any(m.kind == "pinned_host" for m in device.addressable_memories()):
+        return None
+    return SingleDeviceSharding(device, memory_kind="pinned_host")
+
+
 def round_trip(
-    fn: Callable[[jax.Array, Optional[jax.Array]], jax.Array],
+    fn: Callable[[jax.Array, Optional[jax.Array], bool], jax.Array],
     queries: np.ndarray,
     candidate_mask: Optional[np.ndarray] = None,
+    host: Optional[SingleDeviceSharding] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """One device top-K call from the host: upload, dispatch, wait.
 
-    `fn(queries, mask)` is the jitted scorer over the backend's
+    `fn(queries, mask, host_out)` is the jitted scorer over the backend's
     device-resident table; it returns one packed `[Q, 2k]` int32 block
-    (`pack_topk`), which comes back in one copy and is split on the host
-    into (scores, indices) views (`unpack_topk`), bit for bit the
-    program's. Each step is a span of the calling batch:
+    (`pack_topk`), which is split on the host into (scores, indices)
+    views (`unpack_topk`), bit for bit the program's. With `host` (the
+    table's device's pinned host memory, `pinned_host`) the NumPy inputs
+    go straight to `fn`, which runs with `host_out=True`: the jitted call
+    copies them up as it dispatches, and the program writes its block to
+    pinned host memory itself. Without it, the host makes device arrays
+    first and copies the block back. Measured on a TPU v5e at Q=32 (mean
+    of 3,200 calls a placement): 1.33 ms a call with both copies started
+    by the host, 1.06 ms this way; with the queries in pinned host memory
+    too, 1.42 ms. Each step is a span of the calling batch:
 
-      index.upload    the query block (and mask) to device arrays;
+      index.upload    the query block (and mask) to device arrays (without
+                      `host`; with it, nothing: the copy is in dispatch);
       index.dispatch  the jitted call, until it returns its future;
-      index.wait      until the block is on the host: the device's queue
-                      and run, then its one copy back.
+      index.wait      until the block can be read on the host: the
+                      device's queue, the program's run (with `host`, its
+                      own copy of the block to pinned host memory), else
+                      the run and then the block's one copy back.
 
     Dispatch is asynchronous, so the device's transfer and compute time
     land in `index.wait`. No explicit wait is added to split it further:
     on a TPU v5e a `block_until_ready` before the copy cost ~0.11 ms a
     call, ~6% of the whole round trip. The copies and their bytes (the
-    padded query block and mask up, the block down: Q x k x 8 bytes) go
-    to the batch's recorder.
+    padded query block and mask up, the block down: Q x k x 8 bytes), and
+    where the call's output lived, go to the batch's recorder.
     """
     spans = current_spans()
     with spans.span("index.upload"):
-        q = jnp.asarray(queries)
-        mask = None if candidate_mask is None else jnp.asarray(candidate_mask)
+        if host is None:
+            q = jnp.asarray(queries)
+            mask = None if candidate_mask is None else jnp.asarray(candidate_mask)
+        else:
+            q, mask = queries, candidate_mask
     with spans.span("index.dispatch"):
-        block = fn(q, mask)
+        block = fn(q, mask, host is not None)
     with spans.span("index.wait"):
         block = np.asarray(block)
     # what `jax.Array.nbytes` computes, cheaper
     spans.transfer(h2d=q.size * q.dtype.itemsize, d2h=block.nbytes)
     if mask is not None:
         spans.transfer(h2d=mask.size * mask.dtype.itemsize)
+    spans.index_call(pinned=host is not None)
     return unpack_topk(block, block.shape[1] // 2)

@@ -20,7 +20,8 @@ covers masked batches.
 
 It serves through `topk_sim_packed`: `topk_sim` and the packing of its
 result into one block (`core.retrieval.pack_topk`) in one jitted program,
-so a call makes one device-to-host copy.
+so a call makes one device-to-host copy, and, where the device has pinned
+host memory, the program makes it itself (`repro.index.base.round_trip`).
 """
 from __future__ import annotations
 
@@ -32,23 +33,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.retrieval import pack_topk
-from repro.index.base import round_trip
+from repro.index.base import pinned_host, round_trip
 from repro.kernels.topk_sim.ops import topk_sim
 
 __all__ = ["PallasBackend", "topk_sim_packed"]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("k", "use_pallas", "interpret", "host_out"))
 def topk_sim_packed(
     queries: jnp.ndarray,
     table: jnp.ndarray,
     k: int,
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
+    host_out: bool = False,
 ) -> jnp.ndarray:
-    """`topk_sim`'s (scores, indices) as one packed [Q, 2k] int32 block."""
-    return pack_topk(*topk_sim(queries, table, k, use_pallas=use_pallas,
-                               interpret=interpret))
+    """`topk_sim`'s (scores, indices) as one packed [Q, 2k] int32 block;
+    `host_out` as in `core.retrieval.topk_dense`."""
+    scores, idx = topk_sim(queries, table, k, use_pallas=use_pallas,
+                           interpret=interpret)
+    return pack_topk(scores, idx, to_host=host_out)
 
 
 class PallasBackend:
@@ -69,6 +74,7 @@ class PallasBackend:
         self.use_pallas = use_pallas
         self.interpret = interpret
         self._table_j = jnp.asarray(table)
+        self._host = pinned_host(self._table_j)
         self.upload_bytes = self._table_j.nbytes
 
     def topk(
@@ -83,9 +89,9 @@ class PallasBackend:
             "fallback"
         )
         return round_trip(
-            lambda q, _mask: topk_sim_packed(
-                q, self._table_j, k,
-                use_pallas=self.use_pallas, interpret=self.interpret,
+            lambda q, _mask, host_out: topk_sim_packed(
+                q, self._table_j, k, use_pallas=self.use_pallas,
+                interpret=self.interpret, host_out=host_out,
             ),
-            queries,
+            queries, host=self._host,
         )

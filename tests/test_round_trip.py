@@ -8,7 +8,12 @@
   unpacked program;
 * every index call makes exactly one device-to-host copy, and one
   host-to-device copy (two with a mask), through the gateway's
-  `index_transfers_total` too.
+  `index_transfers_total` too;
+* where the table's device lists a `pinned_host` memory (the CPU does), the
+  program writes its block there and the round trip reads it from there,
+  bit-identical to the device-memory program, and counts the call under
+  `index_calls_total{io=pinned_host}`; where it lists none, the call takes
+  the device-memory path and counts under `io=device`.
 """
 import functools
 
@@ -19,6 +24,9 @@ import pytest
 
 from repro.core.retrieval import NEG_INF, pack_topk, similarities, topk_dense, unpack_topk
 from repro.index import ToolIndexManager
+from repro.index import manager as manager_mod
+from repro.index.base import pinned_host
+from repro.index.pallas_backend import topk_sim_packed
 from repro.kernels.topk_sim.kernel import topk_sim_pallas
 from repro.obs import MetricsRegistry
 from repro.obs.trace import SpanRecorder
@@ -143,3 +151,122 @@ def test_gateway_counts_one_copy_back_per_index_call(backend, opts):
     assert reg.histogram("index_step_ms", step="wait").count() == calls
     assert reg.counter("index_transfers_total", dir="d2h").value() == calls
     assert reg.counter("index_transfers_total", dir="h2d").value() == calls + 1
+
+
+# ------------------------------------------------------ pinned host memory
+
+GATEWAY_K = 5  # the benchmark cells' k
+PATHS = {  # the three device paths, and whether each takes a mask
+    "dense": (False, True),
+    "pallas": (False,),  # masked batches go to the exact fallback
+    "exact-fallback": (False, True),
+}
+PINNED_CASES = [
+    pytest.param(path, masked, q, c, id=f"{path}-{'mask' if masked else 'nomask'}-q{q}-k{c}")
+    for path, masks in PATHS.items()
+    for masked in masks
+    for q in (1, 3, 64)
+    for c in (GATEWAY_K, GATEWAY_K * MULT)  # k, and the re-rank candidate count
+]
+
+
+def _manager(path, monkeypatch):
+    opts = PALLAS if path == "pallas" else None
+    manager = ToolIndexManager(_db(), backend="dense" if path == "dense" else "pallas",
+                               backend_opts=opts, async_rebuild=False, metrics=False)
+    if path == "exact-fallback":
+        # the next table's index build fails: every batch, masked or not,
+        # is served by the manager's exact fallback
+        def broken(*args, **kwargs):
+            raise RuntimeError("index build failed")
+
+        monkeypatch.setattr(manager_mod, "_build_backend", broken)
+        manager.db.swap_table(np.roll(manager.db.embeddings, 1, axis=0))
+        assert manager.stats["build_failures"] >= 1
+    return manager
+
+
+def _device_program(path, q, t, c, mask):
+    """The same program with its inputs and block in device memory."""
+    if path == "pallas":
+        return topk_sim_packed(q, t, c, use_pallas=True, interpret=True)
+    return topk_dense(q, t, c, mask)
+
+
+@pytest.mark.parametrize("path,masked,q,c", PINNED_CASES)
+def test_pinned_host_round_trip_is_the_device_program_bit_for_bit(
+        path, masked, q, c, monkeypatch):
+    manager = _manager(path, monkeypatch)
+    queries = _queries(q, seed=q)
+    mask = _mask(q, (T // 2, 9, c, 1)) if masked else None
+    spans = SpanRecorder()
+    with spans.bound():
+        scores, idx, _ = manager.topk(queries, c, mask)
+    assert manager.last_path() == ("exact" if path == "exact-fallback" else f"index:{path}")
+    assert (spans.pinned_calls, spans.device_calls) == (1, 0)
+    assert (spans.d2h_copies, spans.h2d_copies) == (1, 1 + masked)
+    _, table = manager.db.snapshot()
+    want = _device_program(path, jnp.asarray(queries), jnp.asarray(table), c,
+                           None if mask is None else jnp.asarray(mask))
+    assert want.sharding.memory_kind == "device"
+    block = np.concatenate([scores.view(np.int32), idx], axis=1)
+    assert np.array_equal(block, np.asarray(want))
+
+
+def _public_signature(text):
+    (line,) = [ln for ln in text.splitlines() if "func.func public @main" in ln]
+    params, result = line.split("->")
+    return params, result
+
+
+@pytest.mark.parametrize("program", ["topk_dense", "topk_dense-mask", "topk_sim_packed"])
+def test_served_program_writes_pinned_host(program):
+    table = jnp.asarray(np.asarray(_db().snapshot()[1]))
+    assert pinned_host(table) is not None
+    q = _queries()
+    if program == "topk_sim_packed":
+        lowered = topk_sim_packed.lower(q, table, K, use_pallas=True, interpret=True,
+                                        host_out=True)
+    else:
+        mask = _mask(q.shape[0], (T,)) if program.endswith("mask") else None
+        lowered = topk_dense.lower(q, table, K, mask, host_out=True)
+    params, result = _public_signature(lowered.as_text())
+    pinned = 'mhlo.memory_kind = "pinned_host"'
+    assert pinned not in params  # queries, table and mask in device memory
+    assert pinned in result
+
+
+def _router(reg, backend="dense", opts=None):
+    return SemanticRouter(
+        _db(), lambda tok: np.bincount(np.asarray(tok) % D, minlength=D).astype(np.float32),
+        k=K, backend=backend, backend_opts=opts, metrics=reg,
+    )
+
+
+@pytest.mark.parametrize("listed", [True, False], ids=["pinned_host-listed", "no-pinned_host"])
+def test_index_calls_count_where_their_io_lived(listed, monkeypatch):
+    batches = [[np.arange(j, j + 4) for j in range(n)] for n in (1, 3, 5, 2)]
+    masks = np.ones((2, T), np.int32)
+    # served with the queries and block in pinned host memory (the CPU lists it)
+    reference = _router(MetricsRegistry())
+    want = [reference.route_batch(b) for b in batches]
+    want.append(reference.route_batch(batches[-1], masks))
+    if not listed:
+        device_type = type(jax.devices()[0])
+        memories = device_type.addressable_memories
+        monkeypatch.setattr(
+            device_type, "addressable_memories",
+            lambda dev: [m for m in memories(dev) if m.kind != "pinned_host"])
+    reg = MetricsRegistry()
+    router = _router(reg)
+    got = [router.route_batch(b) for b in batches]
+    got.append(router.route_batch(batches[-1], masks))
+    calls = len(got)
+    io, other = ("pinned_host", "device") if listed else ("device", "pinned_host")
+    assert reg.counter("index_calls_total", io=io).value() == calls
+    assert reg.counter("index_calls_total", io=other).value() == 0
+    assert reg.counter("index_transfers_total", dir="d2h").value() == calls
+    assert reg.counter("index_transfers_total", dir="h2d").value() == calls + 1
+    # either placement serves the same bits
+    for g_batch, w_batch in zip(got, want):
+        assert [(g.tools, g.scores) for g in g_batch] == [(w.tools, w.scores) for w in w_batch]
