@@ -29,11 +29,11 @@ The device backends (dense, pallas, and the manager's exact fallback, a
 `DenseBackend`) share one host round trip, `round_trip`: their jitted
 program returns the top-K as one packed `[Q, 2k]` int32 block
 (`core.retrieval.pack_topk`), so each call makes one copy up (two with a
-mask) and one copy back. Where the table's device lists a `pinned_host`
-memory (see `pinned_host`), the NumPy query block goes straight to the
-jitted call, and the program writes its block to pinned host memory by its
-own copy, so the host reads host memory and starts no copy back; elsewhere
-the host copies the block back from device memory. It times its steps as
+mask) and one copy back. The NumPy query block goes straight to the
+jitted call, which copies it up. Where the table's device lists a
+`pinned_host` memory (`has_pinned_host`), the program writes its block
+there by its own copy, so the host reads host memory and starts no copy
+back; elsewhere the host copies the block back from device memory. It times its steps as
 spans of the calling batch (`repro.obs.trace.current_spans`).
 """
 from __future__ import annotations
@@ -41,14 +41,12 @@ from __future__ import annotations
 from typing import Callable, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import SingleDeviceSharding
 
 from repro.core.retrieval import NEG_INF, unpack_topk
 from repro.obs.trace import current_spans
 
-__all__ = ["NEG_INF", "ScorerBackend", "pinned_host", "round_trip"]
+__all__ = ["NEG_INF", "ScorerBackend", "has_pinned_host", "round_trip"]
 
 
 @runtime_checkable
@@ -70,43 +68,38 @@ class ScorerBackend(Protocol):
         ...
 
 
-def pinned_host(table: jax.Array) -> Optional[SingleDeviceSharding]:
-    """Pinned host memory of the device that holds `table`, as a sharding,
-    or None where that device lists no `pinned_host` memory."""
+def has_pinned_host(table: jax.Array) -> bool:
+    """Whether the device that holds `table` lists a `pinned_host` memory."""
     (device,) = table.devices()
-    if not any(m.kind == "pinned_host" for m in device.addressable_memories()):
-        return None
-    return SingleDeviceSharding(device, memory_kind="pinned_host")
+    return any(m.kind == "pinned_host" for m in device.addressable_memories())
 
 
 def round_trip(
-    fn: Callable[[jax.Array, Optional[jax.Array], bool], jax.Array],
+    fn: Callable[[np.ndarray, Optional[np.ndarray]], jax.Array],
     queries: np.ndarray,
     candidate_mask: Optional[np.ndarray] = None,
-    host: Optional[SingleDeviceSharding] = None,
+    host_out: bool = False,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """One device top-K call from the host: upload, dispatch, wait.
 
-    `fn(queries, mask, host_out)` is the jitted scorer over the backend's
+    `fn(queries, mask)` is the jitted scorer over the backend's
     device-resident table; it returns one packed `[Q, 2k]` int32 block
     (`pack_topk`), which is split on the host into (scores, indices)
-    views (`unpack_topk`), bit for bit the program's. With `host` (the
-    table's device's pinned host memory, `pinned_host`) the NumPy inputs
-    go straight to `fn`, which runs with `host_out=True`: the jitted call
-    copies them up as it dispatches, and the program writes its block to
-    pinned host memory itself. Without it, the host makes device arrays
-    first and copies the block back. Measured on a TPU v5e at Q=32 (mean
-    of 3,200 calls a placement): 1.33 ms a call with both copies started
-    by the host, 1.06 ms this way; with the queries in pinned host memory
-    too, 1.42 ms. Each step is a span of the calling batch:
+    views (`unpack_topk`), bit for bit the program's. The NumPy inputs go
+    straight to `fn`: the jitted call copies them up as it dispatches.
+    `host_out` says that `fn` writes its block to pinned host memory by
+    the program's own copy (the backend sets it where the table's device
+    has that memory, `has_pinned_host`); else the host copies the block
+    back from device memory. Each step is a span of the calling batch:
 
-      index.upload    the query block (and mask) to device arrays (without
-                      `host`; with it, nothing: the copy is in dispatch);
-      index.dispatch  the jitted call, until it returns its future;
+      index.upload    the query block (and mask) in the dtypes that cross
+                      (a view unless a dtype must narrow); no copy;
+      index.dispatch  the jitted call, with its copy of the inputs up,
+                      until it returns its future;
       index.wait      until the block can be read on the host: the
-                      device's queue, the program's run (with `host`, its
-                      own copy of the block to pinned host memory), else
-                      the run and then the block's one copy back.
+                      device's queue and the program's run, with its own
+                      copy of the block to pinned host memory (`host_out`),
+                      else the run and then the block's one copy back.
 
     Dispatch is asynchronous, so the device's transfer and compute time
     land in `index.wait`. No explicit wait is added to split it further:
@@ -117,18 +110,20 @@ def round_trip(
     """
     spans = current_spans()
     with spans.span("index.upload"):
-        if host is None:
-            q = jnp.asarray(queries)
-            mask = None if candidate_mask is None else jnp.asarray(candidate_mask)
-        else:
-            q, mask = queries, candidate_mask
+        q = _crossing(queries)
+        mask = None if candidate_mask is None else _crossing(candidate_mask)
     with spans.span("index.dispatch"):
-        block = fn(q, mask, host is not None)
+        block = fn(q, mask)
     with spans.span("index.wait"):
         block = np.asarray(block)
-    # what `jax.Array.nbytes` computes, cheaper
-    spans.transfer(h2d=q.size * q.dtype.itemsize, d2h=block.nbytes)
+    spans.transfer(h2d=q.nbytes, d2h=block.nbytes)
     if mask is not None:
-        spans.transfer(h2d=mask.size * mask.dtype.itemsize)
-    spans.index_call(pinned=host is not None)
+        spans.transfer(h2d=mask.nbytes)
+    spans.index_call(pinned=host_out)
     return unpack_topk(block, block.shape[1] // 2)
+
+
+def _crossing(x: np.ndarray) -> np.ndarray:
+    """`x` in the dtype the jitted call gives it on the device (int64 and
+    float64 narrow to 32 bits unless x64 is enabled)."""
+    return np.asarray(x, dtype=jax.dtypes.canonicalize_dtype(x.dtype))

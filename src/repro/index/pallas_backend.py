@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.retrieval import pack_topk
-from repro.index.base import pinned_host, round_trip
+from repro.index.base import has_pinned_host, round_trip
 from repro.kernels.topk_sim.ops import topk_sim
 
 __all__ = ["PallasBackend", "topk_sim_packed"]
@@ -74,7 +74,7 @@ class PallasBackend:
         self.use_pallas = use_pallas
         self.interpret = interpret
         self._table_j = jnp.asarray(table)
-        self._host = pinned_host(self._table_j)
+        self._host_out = has_pinned_host(self._table_j)
         self.upload_bytes = self._table_j.nbytes
 
     def topk(
@@ -89,9 +89,9 @@ class PallasBackend:
             "fallback"
         )
         return round_trip(
-            lambda q, _mask, host_out: topk_sim_packed(
+            lambda q, _mask: topk_sim_packed(
                 q, self._table_j, k, use_pallas=self.use_pallas,
-                interpret=self.interpret, host_out=host_out,
+                interpret=self.interpret, host_out=self._host_out,
             ),
-            queries, host=self._host,
+            queries, host_out=self._host_out,
         )

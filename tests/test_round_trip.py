@@ -22,13 +22,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.common.bucketing import pad_amount
 from repro.core.retrieval import NEG_INF, pack_topk, similarities, topk_dense, unpack_topk
 from repro.index import ToolIndexManager
 from repro.index import manager as manager_mod
-from repro.index.base import pinned_host
+from repro.index.base import has_pinned_host
 from repro.index.pallas_backend import topk_sim_packed
 from repro.kernels.topk_sim.kernel import topk_sim_pallas
-from repro.obs import MetricsRegistry
+from repro.obs import JitProfiler, MetricsRegistry, stamp_router_costs
 from repro.obs.trace import SpanRecorder
 from repro.router.gateway import SemanticRouter
 from repro.router.tooldb import ToolRecord, ToolsDatabase
@@ -222,7 +223,7 @@ def _public_signature(text):
 @pytest.mark.parametrize("program", ["topk_dense", "topk_dense-mask", "topk_sim_packed"])
 def test_served_program_writes_pinned_host(program):
     table = jnp.asarray(np.asarray(_db().snapshot()[1]))
-    assert pinned_host(table) is not None
+    assert has_pinned_host(table)
     q = _queries()
     if program == "topk_sim_packed":
         lowered = topk_sim_packed.lower(q, table, K, use_pallas=True, interpret=True,
@@ -243,20 +244,30 @@ def _router(reg, backend="dense", opts=None):
     )
 
 
-@pytest.mark.parametrize("listed", [True, False], ids=["pinned_host-listed", "no-pinned_host"])
-def test_index_calls_count_where_their_io_lived(listed, monkeypatch):
+def _hide_pinned_host(monkeypatch):
+    """The device lists no `pinned_host` memory from here on."""
+    device_type = type(jax.devices()[0])
+    memories = device_type.addressable_memories
+    monkeypatch.setattr(
+        device_type, "addressable_memories",
+        lambda dev: [m for m in memories(dev) if m.kind != "pinned_host"])
+
+
+LISTED = pytest.mark.parametrize("listed", [True, False],
+                                 ids=["pinned_host-listed", "no-pinned_host"])
+
+
+@LISTED
+@pytest.mark.parametrize("mask_dtype", [np.int32, np.int64, np.bool_])
+def test_index_calls_count_where_their_io_lived(listed, mask_dtype, monkeypatch):
     batches = [[np.arange(j, j + 4) for j in range(n)] for n in (1, 3, 5, 2)]
-    masks = np.ones((2, T), np.int32)
-    # served with the queries and block in pinned host memory (the CPU lists it)
+    masks = np.ones((2, T), mask_dtype)
+    # served with the block in pinned host memory (the CPU lists it)
     reference = _router(MetricsRegistry())
     want = [reference.route_batch(b) for b in batches]
     want.append(reference.route_batch(batches[-1], masks))
     if not listed:
-        device_type = type(jax.devices()[0])
-        memories = device_type.addressable_memories
-        monkeypatch.setattr(
-            device_type, "addressable_memories",
-            lambda dev: [m for m in memories(dev) if m.kind != "pinned_host"])
+        _hide_pinned_host(monkeypatch)
     reg = MetricsRegistry()
     router = _router(reg)
     got = [router.route_batch(b) for b in batches]
@@ -267,6 +278,33 @@ def test_index_calls_count_where_their_io_lived(listed, monkeypatch):
     assert reg.counter("index_calls_total", io=other).value() == 0
     assert reg.counter("index_transfers_total", dir="d2h").value() == calls
     assert reg.counter("index_transfers_total", dir="h2d").value() == calls + 1
+    # bytes at the dtypes that cross: float32 queries, the mask as the jit
+    # takes it (int64 narrows to int32 without x64)
+    q_rows = sum(len(b) + pad_amount(len(b)) for b in batches + [batches[-1]])
+    mask_rows = 2 + pad_amount(2)
+    item = jax.dtypes.canonicalize_dtype(mask_dtype).itemsize
+    assert item == (1 if mask_dtype is np.bool_ else 4)
+    assert reg.counter("index_transfer_bytes_total", dir="h2d").value() == (
+        q_rows * D * 4 + mask_rows * T * item)
     # either placement serves the same bits
     for g_batch, w_batch in zip(got, want):
         assert [(g.tools, g.scores) for g in g_batch] == [(w.tools, w.scores) for w in w_batch]
+
+
+@LISTED
+def test_cost_stamp_lowers_the_placement_that_serves(listed, monkeypatch):
+    if not listed:
+        _hide_pinned_host(monkeypatch)
+    router = _router(MetricsRegistry())
+    profiler = JitProfiler(jits={"topk_dense": topk_dense})
+    lowered = []
+
+    class Spy:  # records how the stamp lowers the served program
+        def lower(self, *args, **kwargs):
+            lowered.append(kwargs)
+            return topk_dense.lower(*args, **kwargs)
+
+    profiler._fns["topk_dense"] = Spy()
+    stamped = stamp_router_costs(profiler, router, batch_size=3)
+    assert stamped["topk_dense"]["flops"] > 0
+    assert lowered == [{"host_out": listed}]
