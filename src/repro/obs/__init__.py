@@ -94,10 +94,9 @@ route_batch_size (histogram)
 index_step_ms{step=snapshot|upload|dispatch|wait|ivf} (histogram)
     Per-call duration of each index-layer step inside the `score` phase:
     the manager's snapshot + backend lookup, then the device backends' host
-    round trip (`repro.index.base.round_trip`) — `upload` the query block
-    (+ mask) to device arrays (empty where the device has pinned host
-    memory: the jitted call copies the NumPy block up in `dispatch`),
-    `dispatch` the jitted call, `wait` until the packed top-K block can be
+    round trip (`repro.index.base.round_trip`) — `upload` the NumPy query
+    block (+ mask) in the dtypes that cross (no copy), `dispatch` the
+    jitted call, which copies them up, `wait` until the packed top-K block can be
     read on the host (the device's queue and the run land here, dispatch
     being asynchronous, with the program's own copy of the block to pinned
     host memory, or else the block's one copy back);
