@@ -14,10 +14,7 @@ Candidate masking supports MetaTool-style per-query candidate subsets.
 The served programs (`topk_dense`, the Pallas backend's `topk_sim_packed`)
 return their top-K as one packed block (`pack_topk`), so a call costs one
 device-to-host copy; `unpack_topk` splits it on the host without copying.
-`topk_sim` itself keeps its (scores, indices) pair. With `host_out=True`
-they write the block to the device's `pinned_host` memory by their own
-copy (`pack_topk(..., to_host=True)`), so the host reads it from host
-memory and starts no copy back (`repro.index.base.round_trip`).
+`topk_sim` itself keeps its (scores, indices) pair.
 """
 from __future__ import annotations
 
@@ -43,16 +40,14 @@ def similarities(query_emb: jnp.ndarray, tool_emb: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(query_emb, tool_emb.T, precision=jax.lax.Precision.HIGHEST)
 
 
-def pack_topk(scores: jnp.ndarray, idx: jnp.ndarray, to_host: bool = False) -> jnp.ndarray:
+def pack_topk(scores: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """In-jit: (scores [Q, k] float32, indices [Q, k]) -> one [Q, 2k] int32
-    block, the scores' bits first, then the indices; `to_host` writes it to
-    pinned host memory, by the program's own copy.
+    block, the scores' bits first, then the indices.
 
     Packed as int32, never as float32: small indices bitcast to float32
     are subnormals, which a TPU may flush to zero."""
     bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    block = jnp.concatenate([bits, idx.astype(jnp.int32)], axis=1)
-    return jax.device_put(block, jax.memory.Space.Host) if to_host else block
+    return jnp.concatenate([bits, idx.astype(jnp.int32)], axis=1)
 
 
 def unpack_topk(block, k: int) -> tuple[np.ndarray, np.ndarray]:
@@ -62,17 +57,16 @@ def unpack_topk(block, k: int) -> tuple[np.ndarray, np.ndarray]:
     return block[:, :k].view(np.float32), block[:, k:]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "host_out"))
+@functools.partial(jax.jit, static_argnames=("k",))
 def topk_dense(
     query_emb: jnp.ndarray,
     tool_emb: jnp.ndarray,
     k: int,
     candidate_mask: jnp.ndarray | None = None,
-    host_out: bool = False,
 ) -> jnp.ndarray:
     """Top-k per query as one packed [Q, 2k] int32 block (`pack_topk`;
     `unpack_topk` gives (scores, indices)). candidate_mask: [Q,T] {0,1} or
-    None. `host_out`: the block goes out in pinned host memory.
+    None.
 
     The device ops carry their step in the op metadata (`score/`, `topk/`)."""
     with jax.named_scope("score"):
@@ -81,7 +75,7 @@ def topk_dense(
             sims = jnp.where(candidate_mask > 0, sims, NEG_INF)
     with jax.named_scope("topk"):
         scores, idx = jax.lax.top_k(sims, k)
-    return pack_topk(scores, idx, to_host=host_out)
+    return pack_topk(scores, idx)
 
 
 def rank_dense(

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.retrieval import topk_dense
-from repro.index.base import has_pinned_host, round_trip
+from repro.index.base import round_trip
 
 __all__ = ["DenseBackend"]
 
@@ -37,7 +37,6 @@ class DenseBackend:
         self.table_version = int(table_version)
         self.n_tools = table.shape[0]
         self._table_j = jnp.asarray(table)  # device-resident, built once
-        self._host_out = has_pinned_host(self._table_j)
         self.upload_bytes = self._table_j.nbytes
 
     def topk(
@@ -47,7 +46,6 @@ class DenseBackend:
         candidate_mask: Optional[np.ndarray] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
         return round_trip(
-            lambda q, mask: topk_dense(q, self._table_j, k, mask,
-                                       host_out=self._host_out),
-            queries, candidate_mask, self._host_out,
+            lambda q, mask: topk_dense(q, self._table_j, k, mask),
+            queries, candidate_mask,
         )

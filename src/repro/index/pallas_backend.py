@@ -20,8 +20,7 @@ covers masked batches.
 
 It serves through `topk_sim_packed`: `topk_sim` and the packing of its
 result into one block (`core.retrieval.pack_topk`) in one jitted program,
-so a call makes one device-to-host copy, and, where the device has pinned
-host memory, the program makes it itself (`repro.index.base.round_trip`).
+so a call makes one device-to-host copy.
 """
 from __future__ import annotations
 
@@ -33,27 +32,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.retrieval import pack_topk
-from repro.index.base import has_pinned_host, round_trip
+from repro.index.base import round_trip
 from repro.kernels.topk_sim.ops import topk_sim
 
 __all__ = ["PallasBackend", "topk_sim_packed"]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k", "use_pallas", "interpret", "host_out"))
+@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
 def topk_sim_packed(
     queries: jnp.ndarray,
     table: jnp.ndarray,
     k: int,
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
-    host_out: bool = False,
 ) -> jnp.ndarray:
-    """`topk_sim`'s (scores, indices) as one packed [Q, 2k] int32 block;
-    `host_out` as in `core.retrieval.topk_dense`."""
-    scores, idx = topk_sim(queries, table, k, use_pallas=use_pallas,
-                           interpret=interpret)
-    return pack_topk(scores, idx, to_host=host_out)
+    """`topk_sim`'s (scores, indices) as one packed [Q, 2k] int32 block."""
+    return pack_topk(*topk_sim(queries, table, k, use_pallas=use_pallas,
+                               interpret=interpret))
 
 
 class PallasBackend:
@@ -74,7 +69,6 @@ class PallasBackend:
         self.use_pallas = use_pallas
         self.interpret = interpret
         self._table_j = jnp.asarray(table)
-        self._host_out = has_pinned_host(self._table_j)
         self.upload_bytes = self._table_j.nbytes
 
     def topk(
@@ -90,8 +84,8 @@ class PallasBackend:
         )
         return round_trip(
             lambda q, _mask: topk_sim_packed(
-                q, self._table_j, k, use_pallas=self.use_pallas,
-                interpret=self.interpret, host_out=self._host_out,
+                q, self._table_j, k,
+                use_pallas=self.use_pallas, interpret=self.interpret,
             ),
-            queries, host_out=self._host_out,
+            queries,
         )

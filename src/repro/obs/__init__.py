@@ -96,26 +96,19 @@ index_step_ms{step=snapshot|upload|dispatch|wait|ivf} (histogram)
     the manager's snapshot + backend lookup, then the device backends' host
     round trip (`repro.index.base.round_trip`) — `upload` the NumPy query
     block (+ mask) in the dtypes that cross (no copy), `dispatch` the
-    jitted call, which copies them up, `wait` until the packed top-K block can be
-    read on the host (the device's queue and the run land here, dispatch
-    being asynchronous, with the program's own copy of the block to pinned
-    host memory, or else the block's one copy back);
-    or the IVF backend's one host span. Recorded by the gateway with its
-    phases.
+    jitted call, which copies them up, `wait` until the packed top-K block
+    is on the host (the device's queue and run land here: dispatch is
+    asynchronous; then the block's one copy); or the IVF backend's one
+    host span. Recorded by the gateway with its phases.
 index_transfer_bytes_total{dir=h2d|d2h} (counter)
     Bytes moved between host and device by the index layer: per call the
     padded query block (+ mask) up and the packed scores + indices down
-    (gateway registry), whichever side starts the copy; per build the table
-    a device backend uploads (manager registry; the same one by default).
+    (gateway registry), per build the table a device backend uploads
+    (manager registry; the same one by default).
 index_transfers_total{dir=h2d|d2h} (counter)
     Host-device copies the index layer makes, beside those bytes: per
     device call one up (two with a mask) and one down, the packed block
     (gateway registry); per build one up (manager registry).
-index_calls_total{io=pinned_host|device} (counter)
-    Device index calls (`round_trip`) by where their packed block was
-    written: `pinned_host` where the table's device lists that memory and
-    the program copies the block there itself, `device` where the host
-    copies it back (gateway registry).
 fit_phase_ms{phase=refine|gate|grow} (histogram)
     Host duration of each phase of `launch.serve.build_router`'s offline
     fit, one sample per build (one `SpanRecorder` per fit): `refine` the

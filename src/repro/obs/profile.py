@@ -172,7 +172,6 @@ def stamp_router_costs(
     import jax.numpy as jnp
 
     from repro.common.bucketing import pad_amount
-    from repro.index.base import has_pinned_host
 
     q = int(batch_size)
     q_pad = q + pad_amount(q)
@@ -189,10 +188,8 @@ def stamp_router_costs(
         else min(router.k, n_t)
     )
     if "topk_dense" in profiler.names():
-        table = jnp.asarray(emb)
-        # the block's placement `DenseBackend` serves with
         stamped["topk_dense"] = profiler.stamp_cost(
-            "topk_dense", qblock, table, c, host_out=has_pinned_host(table)
+            "topk_dense", qblock, jnp.asarray(emb), c
         )
     if "adapter_apply" in profiler.names() and stages.has_adapter:
         stamped["adapter_apply"] = profiler.stamp_cost(

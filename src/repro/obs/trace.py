@@ -110,13 +110,11 @@ class SpanRecorder:
     trace is active. `bound()` makes the recorder the calling thread's
     `current_spans()`: that is how the index layer records into the batch
     that called it without a parameter, and adds the copies it makes
-    between host and device, and their bytes, with `transfer`, and each
-    device call by where its I/O lived with `index_call`.
+    between host and device, and their bytes, with `transfer`.
     """
 
     __slots__ = ("t0", "profiling", "enabled", "h2d_bytes", "d2h_bytes",
-                 "h2d_copies", "d2h_copies", "pinned_calls", "device_calls",
-                 "_spans")
+                 "h2d_copies", "d2h_copies", "_spans")
 
     def __init__(self, enabled: bool = True, t0: Optional[float] = None):
         self.t0 = clock.perf() if t0 is None else t0
@@ -125,7 +123,6 @@ class SpanRecorder:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.h2d_copies = self.d2h_copies = 0
-        self.pinned_calls = self.device_calls = 0
         self._spans: List[_Span] = []
 
     def span(self, name: str, start: Optional[float] = None):
@@ -145,13 +142,6 @@ class SpanRecorder:
         if d2h:
             self.d2h_bytes += int(d2h)
             self.d2h_copies += 1
-
-    def index_call(self, pinned: bool) -> None:
-        """One device index call, its I/O in pinned host memory or not."""
-        if pinned:
-            self.pinned_calls += 1
-        else:
-            self.device_calls += 1
 
     def entry_wall(self) -> float:
         """The entry stamp `t0` on the wall clock (for exported records)."""
@@ -197,13 +187,9 @@ class _NullRecorder(SpanRecorder):
         self.profiling = self.enabled = False
         self.h2d_bytes = self.d2h_bytes = 0
         self.h2d_copies = self.d2h_copies = 0
-        self.pinned_calls = self.device_calls = 0
         self._spans = []
 
     def transfer(self, h2d: int = 0, d2h: int = 0) -> None:
-        pass
-
-    def index_call(self, pinned: bool) -> None:
         pass
 
 

@@ -141,10 +141,6 @@ class _GatewayInstruments:
             d: registry.counter("index_transfers_total", dir=d)
             for d in ("h2d", "d2h")
         }
-        self.index_calls = {
-            io: registry.counter("index_calls_total", io=io)
-            for io in ("pinned_host", "device")
-        }
         self.obs_ms = registry.histogram("route_obs_ms")
         self.outcomes_dropped = registry.counter("route_outcomes_dropped_total")
         # top-1/top-2 score gap per query (routing confidence; a collapsing
@@ -663,10 +659,6 @@ class SemanticRouter:
             obs.transfer_bytes["d2h"].inc(spans.d2h_bytes)
             obs.transfers["h2d"].inc(spans.h2d_copies)
             obs.transfers["d2h"].inc(spans.d2h_copies)
-            if spans.pinned_calls:
-                obs.index_calls["pinned_host"].inc(spans.pinned_calls)
-            if spans.device_calls:
-                obs.index_calls["device"].inc(spans.device_calls)
         if top_scores.shape[1] >= 2:
             # sampled 1-in-4 batches: the gap histogram feeds percentile
             # summaries (confidence()), which a quarter of the traffic

@@ -9,11 +9,11 @@
 * every index call makes exactly one device-to-host copy, and one
   host-to-device copy (two with a mask), through the gateway's
   `index_transfers_total` too;
-* where the table's device lists a `pinned_host` memory (the CPU does), the
-  program writes its block there and the round trip reads it from there,
-  bit-identical to the device-memory program, and counts the call under
-  `index_calls_total{io=pinned_host}`; where it lists none, the call takes
-  the device-memory path and counts under `io=device`.
+* the round trip hands the jit its NumPy inputs and returns the block of
+  the program called on device arrays, bit for bit, on every device path,
+  masked or not, Q 1/3/64, k and the re-rank candidate count;
+* the bytes counted up are those of the dtypes that cross (an int64 mask
+  narrows to int32).
 """
 import functools
 
@@ -26,10 +26,9 @@ from repro.common.bucketing import pad_amount
 from repro.core.retrieval import NEG_INF, pack_topk, similarities, topk_dense, unpack_topk
 from repro.index import ToolIndexManager
 from repro.index import manager as manager_mod
-from repro.index.base import has_pinned_host
 from repro.index.pallas_backend import topk_sim_packed
 from repro.kernels.topk_sim.kernel import topk_sim_pallas
-from repro.obs import JitProfiler, MetricsRegistry, stamp_router_costs
+from repro.obs import MetricsRegistry
 from repro.obs.trace import SpanRecorder
 from repro.router.gateway import SemanticRouter
 from repro.router.tooldb import ToolRecord, ToolsDatabase
@@ -154,7 +153,7 @@ def test_gateway_counts_one_copy_back_per_index_call(backend, opts):
     assert reg.counter("index_transfers_total", dir="h2d").value() == calls + 1
 
 
-# ------------------------------------------------------ pinned host memory
+# ------------------------------------------------- NumPy inputs to the jit
 
 GATEWAY_K = 5  # the benchmark cells' k
 PATHS = {  # the three device paths, and whether each takes a mask
@@ -162,7 +161,7 @@ PATHS = {  # the three device paths, and whether each takes a mask
     "pallas": (False,),  # masked batches go to the exact fallback
     "exact-fallback": (False, True),
 }
-PINNED_CASES = [
+PATH_CASES = [
     pytest.param(path, masked, q, c, id=f"{path}-{'mask' if masked else 'nomask'}-q{q}-k{c}")
     for path, masks in PATHS.items()
     for masked in masks
@@ -188,14 +187,14 @@ def _manager(path, monkeypatch):
 
 
 def _device_program(path, q, t, c, mask):
-    """The same program with its inputs and block in device memory."""
+    """The same program, called on device arrays."""
     if path == "pallas":
         return topk_sim_packed(q, t, c, use_pallas=True, interpret=True)
     return topk_dense(q, t, c, mask)
 
 
-@pytest.mark.parametrize("path,masked,q,c", PINNED_CASES)
-def test_pinned_host_round_trip_is_the_device_program_bit_for_bit(
+@pytest.mark.parametrize("path,masked,q,c", PATH_CASES)
+def test_numpy_round_trip_is_the_device_program_bit_for_bit(
         path, masked, q, c, monkeypatch):
     manager = _manager(path, monkeypatch)
     queries = _queries(q, seed=q)
@@ -204,37 +203,12 @@ def test_pinned_host_round_trip_is_the_device_program_bit_for_bit(
     with spans.bound():
         scores, idx, _ = manager.topk(queries, c, mask)
     assert manager.last_path() == ("exact" if path == "exact-fallback" else f"index:{path}")
-    assert (spans.pinned_calls, spans.device_calls) == (1, 0)
     assert (spans.d2h_copies, spans.h2d_copies) == (1, 1 + masked)
     _, table = manager.db.snapshot()
     want = _device_program(path, jnp.asarray(queries), jnp.asarray(table), c,
                            None if mask is None else jnp.asarray(mask))
-    assert want.sharding.memory_kind == "device"
     block = np.concatenate([scores.view(np.int32), idx], axis=1)
     assert np.array_equal(block, np.asarray(want))
-
-
-def _public_signature(text):
-    (line,) = [ln for ln in text.splitlines() if "func.func public @main" in ln]
-    params, result = line.split("->")
-    return params, result
-
-
-@pytest.mark.parametrize("program", ["topk_dense", "topk_dense-mask", "topk_sim_packed"])
-def test_served_program_writes_pinned_host(program):
-    table = jnp.asarray(np.asarray(_db().snapshot()[1]))
-    assert has_pinned_host(table)
-    q = _queries()
-    if program == "topk_sim_packed":
-        lowered = topk_sim_packed.lower(q, table, K, use_pallas=True, interpret=True,
-                                        host_out=True)
-    else:
-        mask = _mask(q.shape[0], (T,)) if program.endswith("mask") else None
-        lowered = topk_dense.lower(q, table, K, mask, host_out=True)
-    params, result = _public_signature(lowered.as_text())
-    pinned = 'mhlo.memory_kind = "pinned_host"'
-    assert pinned not in params  # queries, table and mask in device memory
-    assert pinned in result
 
 
 def _router(reg, backend="dense", opts=None):
@@ -244,67 +218,27 @@ def _router(reg, backend="dense", opts=None):
     )
 
 
-def _hide_pinned_host(monkeypatch):
-    """The device lists no `pinned_host` memory from here on."""
-    device_type = type(jax.devices()[0])
-    memories = device_type.addressable_memories
-    monkeypatch.setattr(
-        device_type, "addressable_memories",
-        lambda dev: [m for m in memories(dev) if m.kind != "pinned_host"])
-
-
-LISTED = pytest.mark.parametrize("listed", [True, False],
-                                 ids=["pinned_host-listed", "no-pinned_host"])
-
-
-@LISTED
 @pytest.mark.parametrize("mask_dtype", [np.int32, np.int64, np.bool_])
-def test_index_calls_count_where_their_io_lived(listed, mask_dtype, monkeypatch):
+def test_h2d_bytes_are_those_of_the_dtypes_that_cross(mask_dtype):
     batches = [[np.arange(j, j + 4) for j in range(n)] for n in (1, 3, 5, 2)]
-    masks = np.ones((2, T), mask_dtype)
-    # served with the block in pinned host memory (the CPU lists it)
     reference = _router(MetricsRegistry())
     want = [reference.route_batch(b) for b in batches]
-    want.append(reference.route_batch(batches[-1], masks))
-    if not listed:
-        _hide_pinned_host(monkeypatch)
+    want.append(reference.route_batch(batches[-1], np.ones((2, T), np.int32)))
     reg = MetricsRegistry()
     router = _router(reg)
     got = [router.route_batch(b) for b in batches]
-    got.append(router.route_batch(batches[-1], masks))
+    got.append(router.route_batch(batches[-1], np.ones((2, T), mask_dtype)))
     calls = len(got)
-    io, other = ("pinned_host", "device") if listed else ("device", "pinned_host")
-    assert reg.counter("index_calls_total", io=io).value() == calls
-    assert reg.counter("index_calls_total", io=other).value() == 0
     assert reg.counter("index_transfers_total", dir="d2h").value() == calls
     assert reg.counter("index_transfers_total", dir="h2d").value() == calls + 1
-    # bytes at the dtypes that cross: float32 queries, the mask as the jit
-    # takes it (int64 narrows to int32 without x64)
+    # float32 queries, the mask as the jit takes it (int64 narrows to
+    # int32 without x64)
     q_rows = sum(len(b) + pad_amount(len(b)) for b in batches + [batches[-1]])
     mask_rows = 2 + pad_amount(2)
     item = jax.dtypes.canonicalize_dtype(mask_dtype).itemsize
     assert item == (1 if mask_dtype is np.bool_ else 4)
     assert reg.counter("index_transfer_bytes_total", dir="h2d").value() == (
         q_rows * D * 4 + mask_rows * T * item)
-    # either placement serves the same bits
+    # any mask dtype serves the same bits
     for g_batch, w_batch in zip(got, want):
         assert [(g.tools, g.scores) for g in g_batch] == [(w.tools, w.scores) for w in w_batch]
-
-
-@LISTED
-def test_cost_stamp_lowers_the_placement_that_serves(listed, monkeypatch):
-    if not listed:
-        _hide_pinned_host(monkeypatch)
-    router = _router(MetricsRegistry())
-    profiler = JitProfiler(jits={"topk_dense": topk_dense})
-    lowered = []
-
-    class Spy:  # records how the stamp lowers the served program
-        def lower(self, *args, **kwargs):
-            lowered.append(kwargs)
-            return topk_dense.lower(*args, **kwargs)
-
-    profiler._fns["topk_dense"] = Spy()
-    stamped = stamp_router_costs(profiler, router, batch_size=3)
-    assert stamped["topk_dense"]["flops"] > 0
-    assert lowered == [{"host_out": listed}]
